@@ -78,32 +78,26 @@ def run(backend: str = "vector") -> List[Tuple[str, float, float]]:
 
     # sorted-coordinate intersection (ExTensor skip-ahead -> TPU)
     ac = ops.pad_sorted(np.sort(rng.choice(100000, 2000,
-                                           replace=False)).astype(
-                            np.int32), 512)
+                                           replace=False)).astype(np.int32))
     bc = ops.pad_sorted(np.sort(rng.choice(100000, 4000,
-                                           replace=False)).astype(
-                            np.int32), 512)
-    us, got = _t(lambda a_, b_: ops.intersect_sorted(a_, b_, block=512),
-                 jnp.asarray(ac), jnp.asarray(bc))
+                                           replace=False)).astype(np.int32))
+    us, got = _t(ops.intersect_sorted, jnp.asarray(ac), jnp.asarray(bc))
     err = float(jnp.max(jnp.abs(
         got - ref.intersect_sorted_ref(ac, bc))))
     rows.append(("kernels/intersect_sorted/interpret", us, err))
 
-    # sorted-union / merge-path kernel (interpret) vs numpy merge
-    am = ops.pad_sorted(np.sort(rng.choice(50000, 1500,
-                                           replace=False)).astype(np.int32),
-                        256)
-    bm = ops.pad_sorted(np.sort(rng.choice(50000, 2500,
-                                           replace=False)).astype(np.int32),
-                        256)
-    interpret = jax.default_backend() != "tpu"
-    us, (merged, _src) = _t(
-        lambda a_, b_: ops.merge_sorted(a_, b_, block=256,
-                                        interpret=interpret),
-        jnp.asarray(am), jnp.asarray(bm))
-    want = np.sort(np.concatenate([am, bm]))
-    err = float(np.max(np.abs(np.asarray(merged) - want)))
-    rows.append(("kernels/merge_sorted/interpret", us, err))
+    # 2-way stable merge ranks (the union kernel) vs numpy merge
+    # (both rows pad to the same 4096-key bucket)
+    am = np.sort(rng.choice(50000, 2500, replace=False)).astype(np.int32)
+    bm = np.sort(rng.choice(50000, 3500, replace=False)).astype(np.int32)
+    stacked = np.stack([ops.pad_sorted(am), ops.pad_sorted(bm)])
+    us, ranks = _t(ops.multi_merge_ranks, jnp.asarray(stacked))
+    ranks = np.asarray(ranks)
+    merged = np.empty(len(am) + len(bm), np.int64)
+    merged[ranks[0, :len(am)]] = am
+    merged[ranks[1, :len(bm)]] = bm
+    err = float(np.max(np.abs(merged - np.sort(np.concatenate([am, bm])))))
+    rows.append(("kernels/multi_merge_ranks/interpret", us, err))
 
     # execution-backend co-iteration micro-bench (real call path of the
     # intersect/union primitives)

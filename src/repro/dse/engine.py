@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import itertools
 import math
+import os
 import time
 import traceback as _tb
 from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor
@@ -212,11 +213,38 @@ class _ReplayFeed:
 _WORKER_ENGINE: Optional["SweepEngine"] = None
 
 
-def _pool_init(inputs, var_shapes, engine_kw, fault_payload) -> None:
-    """Per-process initializer: one engine singleton per worker, and
-    the parent's fault injector re-installed so the fault contract
-    survives the process boundary under fork AND spawn."""
+#: a sweep worker's environment on a TPU host: the parent holds the
+#: chip (one process per chip), so workers run JAX on the CPU and the
+#: numpy kernel lowerings
+WORKER_ENV_ON_TPU = {"JAX_PLATFORMS": "cpu", "REPRO_KERNEL_BACKEND": "numpy"}
+
+
+def _worker_pool(workers: int, initargs: tuple):
+    """The process pool of ``executor='process'``.  On a host whose JAX
+    backend is TPU the workers start with ``spawn`` (a forked child
+    would inherit the parent's hold on the chip) and stay off the chip
+    (``WORKER_ENV_ON_TPU``); elsewhere ``fork`` where available."""
+    import multiprocessing as mp
+    from concurrent.futures import ProcessPoolExecutor
+
+    import jax
+    env: Dict[str, str] = {}
+    if jax.default_backend() == "tpu":
+        method, env = "spawn", dict(WORKER_ENV_ON_TPU)
+    else:
+        method = "fork" if "fork" in mp.get_all_start_methods() else "spawn"
+    return ProcessPoolExecutor(
+        max_workers=workers, mp_context=mp.get_context(method),
+        initializer=_pool_init, initargs=(env,) + tuple(initargs))
+
+
+def _pool_init(env, inputs, var_shapes, engine_kw, fault_payload) -> None:
+    """Per-process initializer: the worker's environment (set before
+    anything in it initializes a JAX backend), one engine singleton per
+    worker, and the parent's fault injector re-installed so the fault
+    contract survives the process boundary under fork AND spawn."""
     global _WORKER_ENGINE
+    os.environ.update(env)
     if fault_payload is not None:
         from repro.testing.faults import FaultInjector, install_injector
         specs, seed = fault_payload
@@ -761,9 +789,6 @@ class SweepEngine:
         each worker runs its own batched engine.  Chunk size is bounded
         by ``checkpoint_every`` so the parent checkpoints at a
         comparable cadence to the serial path."""
-        import multiprocessing as mp
-        from concurrent.futures import ProcessPoolExecutor
-
         chunk = max(1, math.ceil(len(todo) / workers))
         if checkpoint_every > 0:
             chunk = min(chunk, max(checkpoint_every, 1))
@@ -783,13 +808,9 @@ class SweepEngine:
             fault_payload = ([replace(sp, calls=0, fired=0)
                               for sp in inj.specs], inj.seed)
 
-        method = "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-        ctx = mp.get_context(method)
-        with ProcessPoolExecutor(
-                max_workers=min(workers, len(chunks)), mp_context=ctx,
-                initializer=_pool_init,
-                initargs=(self.inputs, self.var_shapes, engine_kw,
-                          fault_payload)) as pool:
+        with _worker_pool(min(workers, len(chunks)),
+                          (self.inputs, self.var_shapes, engine_kw,
+                           fault_payload)) as pool:
             futs = {pool.submit(_pool_run, c): c for c in chunks}
             pending = set(futs)
             while pending:

@@ -12,11 +12,12 @@ kernel backend and the registry that selects between them:
   * ``jax-jit``          the same formulations as jitted XLA programs
                          (pow2-padded shapes to bound retraces, x64
                          enabled so packed int64 keys survive).
-  * ``pallas-interpret`` the Pallas kernels (`intersect_sorted`,
-                         ``merge_sorted``, ``multi_merge_ranks``) run in
-                         interpret mode -- the CI leg that keeps the
-                         kernel bodies from bit-rotting on CPU runners.
-  * ``pallas-tpu``       the same kernels compiled to Mosaic; requires a
+  * ``pallas-interpret`` the Pallas rank kernel behind
+                         ``intersect_sorted`` / ``multi_merge_ranks``
+                         (``kernels/intersect.py``) run in interpret mode
+                         -- the CI leg that keeps the kernel body from
+                         bit-rotting on CPU runners.
+  * ``pallas-tpu``       the same kernel compiled to Mosaic; requires a
                          TPU backend and refuses to resolve without one.
 
 Selection order: an explicit ``VectorBackend(kernel_backend=...)``
@@ -29,7 +30,9 @@ lowering -- positions, union orders, and float accumulation order all
 included.  Inputs outside a backend's admissible domain (e.g. keys
 beyond int32 for the Pallas kernels, semirings without a vectorized
 reduction for the jax scatter path) delegate to the numpy lowering per
-call, so parity is preserved rather than approximated.
+call, so parity is preserved rather than approximated.  Each such
+delegation counts on ``kernel.host_delegation/<seam>``, and each
+program launched on the JAX device on ``kernel.device_call/<seam>``.
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass, replace
+from pathlib import Path
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -195,6 +199,48 @@ class NumpyKernels:
 
 
 # ---------------------------------------------------------------------- #
+# device launches: counted, waited for, compiled into a persistent cache
+# ---------------------------------------------------------------------- #
+#: where compiled programs persist when ``$JAX_COMPILATION_CACHE_DIR``
+#: does not say: a fixed directory of the checkout (the path is part of
+#: the cache key, so a directory that moves never hits)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def compile_cache_dir() -> str:
+    """The persistent compile cache's directory on a TPU host."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CACHE_DIR)
+
+
+@functools.cache
+def _init_device() -> None:
+    """First use of a device backend: on a TPU, keep every compile
+    (kernels compile in about a second, under JAX's default threshold)
+    in the persistent cache.  JAX reads ``$JAX_COMPILATION_CACHE_DIR``
+    itself; CPU runs configure nothing and write nothing."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
+def _device(seam: str, fn, *args, **kwargs) -> np.ndarray:
+    """Launch one seam program and wait for it before its result
+    leaves the device."""
+    import jax
+    out = jax.block_until_ready(fn(*args, **kwargs))
+    _obs_metrics().counter("kernel.device_call/" + seam).inc()
+    return np.asarray(out)
+
+
+def _delegated(seam: str) -> None:
+    """A device backend handing one seam call to the numpy lowering."""
+    _obs_metrics().counter("kernel.host_delegation/" + seam).inc()
+
+
+# ---------------------------------------------------------------------- #
 # jax-jit: the same formulations as XLA programs
 # ---------------------------------------------------------------------- #
 def _pad_pow2(a: np.ndarray, fill) -> np.ndarray:
@@ -212,7 +258,7 @@ def _pad_pow2(a: np.ndarray, fill) -> np.ndarray:
 @functools.cache
 def _jx():
     """Jitted seam programs, built once.  All run under
-    ``enable_x64`` (packed offset keys reach 2^62)."""
+    ``jax.enable_x64`` (packed offset keys reach 2^62)."""
     import jax
     import jax.numpy as jnp
 
@@ -255,39 +301,46 @@ class JaxJitKernels(NumpyKernels):
     Positions/unions are the identical binary-search formulation
     (bit-exact by construction); the float segmented reduction uses an
     XLA scatter-add, which applies duplicate updates in stream order on
-    CPU/TPU -- the same sequential fold as the bincount oracle (parity
-    is CI-asserted, not assumed)."""
+    CPU -- the same sequential fold as the bincount oracle (parity is
+    CI-asserted, not assumed).  A TPU emulates float64: a value copied
+    to a v5e and back already differs in its last bits, so there every
+    segmented reduction delegates to numpy (``f64_exact``)."""
 
     name = "jax-jit"
 
-    def _jpositions(self, hay: np.ndarray, probes: np.ndarray
-                    ) -> np.ndarray:
+    def __init__(self):
+        import jax
+        #: the device holds float64 exactly (false on a TPU)
+        self.f64_exact = jax.default_backend() != "tpu"
+
+    def _jpositions(self, hay: np.ndarray, probes: np.ndarray,
+                    seam: str) -> np.ndarray:
+        import jax
         positions, _, _, _, _ = _jx()
-        from jax.experimental import enable_x64
-        with enable_x64():
-            out = positions(_pad_pow2(hay, _I64_PAD),
-                            _pad_pow2(probes, _I64_PAD))
+        with jax.enable_x64(True):
+            out = _device(seam, positions, _pad_pow2(hay, _I64_PAD),
+                          _pad_pow2(probes, _I64_PAD))
         # hits against hay's pad tail are pad probes only (real keys
         # are < 2^63-1), already sliced off; misses are already -1
-        return np.asarray(out)[:len(probes)].astype(np.int64)
+        return out[:len(probes)].astype(np.int64)
 
     def intersect_keys(self, a, b):
         a = np.asarray(a, dtype=np.int64)
         b = np.asarray(b, dtype=np.int64)
         if len(a) == 0 or len(b) == 0:
             return np.full(len(a), -1, dtype=np.int64)
-        return self._jpositions(b, a)
+        return self._jpositions(b, a, "intersect_keys")
 
     def _positions(self, a, u):
-        return self._jpositions(a, u)
+        return self._jpositions(a, u, "union_k_keys")
 
     def _merged_union(self, arrays):
+        import jax
         _, merge_sort, _, _, _ = _jx()
-        from jax.experimental import enable_x64
         total = sum(len(a) for a in arrays)
         cat = _pad_pow2(np.concatenate(arrays), _I64_PAD)
-        with enable_x64():
-            merged = np.asarray(merge_sort(cat))[:total]
+        with jax.enable_x64(True):
+            merged = _device("union_k_keys", merge_sort, cat)[:total]
         keep = np.ones(total, dtype=bool)
         keep[1:] = merged[1:] != merged[:-1]
         return merged[keep]
@@ -298,8 +351,9 @@ class JaxJitKernels(NumpyKernels):
         if len(probes) == 0 or len(hay) == 0:
             return np.full(len(probes), -1, dtype=np.int64)
         if int(probes.max()) >= _I64_PAD:
+            _delegated("lookup_keys")
             return super().lookup_keys(hay, probes)
-        return self._jpositions(hay, probes)
+        return self._jpositions(hay, probes, "lookup_keys")
 
     def segmented_reduce(self, vals, starts, semiring=None,
                          group_ids=None):
@@ -312,7 +366,9 @@ class JaxJitKernels(NumpyKernels):
         ufunc = None if semiring is None else semiring.add_ufunc
         is_sum = (semiring is None or semiring.add_vec is np.add) and \
             vals.dtype == np.float64
-        if not is_sum and ufunc not in (np.minimum, np.maximum):
+        if not self.f64_exact or (
+                not is_sum and ufunc not in (np.minimum, np.maximum)):
+            _delegated("segmented_reduce")
             return super().segmented_reduce(vals, starts, semiring,
                                             group_ids)
         gids = group_ids
@@ -324,8 +380,8 @@ class JaxJitKernels(NumpyKernels):
         # pad the scatter stream with writes to a dummy slot past the
         # real groups, so the output length is a pow2 static shape
         out_len = 1 << max(n_groups + 1, 2).bit_length()
+        import jax
         _, _, seg_sum, seg_min, seg_max = _jx()
-        from jax.experimental import enable_x64
         fill = 0.0 if is_sum else (np.inf if ufunc is np.minimum
                                    else -np.inf)
         pv = _pad_pow2(np.ascontiguousarray(vals, dtype=np.float64), fill)
@@ -333,9 +389,9 @@ class JaxJitKernels(NumpyKernels):
         pg[:n] = gids
         fn = seg_sum if is_sum else (seg_min if ufunc is np.minimum
                                      else seg_max)
-        with enable_x64():
-            out = fn(pv, pg, int(out_len))
-        res = np.asarray(out)[:n_groups]
+        with jax.enable_x64(True):
+            res = _device("segmented_reduce", fn, pv, pg,
+                          int(out_len))[:n_groups]
         if vals.dtype != np.float64:
             res = res.astype(vals.dtype)
         return res
@@ -349,17 +405,27 @@ def _fits_i32(a: np.ndarray) -> bool:
 
 
 class PallasKernels(NumpyKernels):
-    """The Pallas kernels behind the seams: skip-ahead intersection,
-    merge-path 2-way union, k-ary ``multi_merge_ranks``.  Kernel input
-    contracts are int32 keys padded with INT32_MAX to a block multiple;
-    inputs whose key domain exceeds int32 delegate to the numpy
-    lowering per call (parity over partial coverage).  The segmented
-    reduction inherits the numpy lowering -- a segmented-scan kernel is
-    the next seam to move on-device."""
+    """The Pallas rank kernel behind the seams (``kernels/intersect.py``):
+    intersection positions for ``intersect_keys`` / ``lookup_keys`` and
+    stable k-way merge ranks for the unions.  Keys go to the kernel as
+    int32, padded with INT32_MAX to a power-of-two bucket.  Inputs whose
+    key domain exceeds int32 delegate to the numpy lowering per call; so
+    does every segmented reduction, which has no kernel.  Each delegation
+    counts on ``kernel.host_delegation/<seam>``."""
 
     def __init__(self, interpret: bool):
         self.interpret = interpret
         self.name = "pallas-interpret" if interpret else "pallas-tpu"
+
+    def _isect(self, a: np.ndarray, b: np.ndarray, seam: str
+               ) -> np.ndarray:
+        from repro.kernels import intersect as _isect
+        from repro.kernels import ops as _ops
+        idx = _device(seam, _isect.intersect_sorted,
+                      _ops.pad_sorted(a.astype(np.int32)),
+                      _ops.pad_sorted(b.astype(np.int32)),
+                      interpret=self.interpret)
+        return idx[:len(a)].astype(np.int64)
 
     def intersect_keys(self, a, b):
         a = np.asarray(a, dtype=np.int64)
@@ -367,49 +433,27 @@ class PallasKernels(NumpyKernels):
         if len(a) == 0 or len(b) == 0:
             return np.full(len(a), -1, dtype=np.int64)
         if not (_fits_i32(a) and _fits_i32(b)):
+            _delegated("intersect_keys")
             return super().intersect_keys(a, b)
-        import jax.numpy as jnp
-        from repro.kernels import intersect as _isect
-        from repro.kernels import ops as _ops
-        pa = _ops.pad_sorted(a.astype(np.int32), 512)
-        pb = _ops.pad_sorted(b.astype(np.int32), 512)
-        idx = np.asarray(_isect.intersect_sorted(
-            jnp.asarray(pa), jnp.asarray(pb), block=512,
-            interpret=self.interpret))[:len(a)]
-        return idx.astype(np.int64)
+        return self._isect(a, b, "intersect_keys")
 
     def _merged_union(self, arrays):
         if not all(_fits_i32(a) for a in arrays):
+            _delegated("union_k_keys")
             return super()._merged_union(arrays)
-        import jax.numpy as jnp
-        from repro.kernels import ops as _ops
-        if len(arrays) == 2:
-            # merge-path kernel + host dedup; pads merge to the tail
-            pa32 = _ops.pad_sorted(arrays[0].astype(np.int32), 256)
-            pb32 = _ops.pad_sorted(arrays[1].astype(np.int32), 256)
-            merged, _ = _ops.merge_sorted(
-                jnp.asarray(pa32), jnp.asarray(pb32), block=256,
-                interpret=self.interpret)
-            merged = np.asarray(merged, dtype=np.int64)
-            merged = merged[merged < _I32_MAX]
-        else:
-            # k-ary multi-merge: every element finds its global rank in
-            # the stable merge in one launch
-            n_pad = max(len(_ops.pad_sorted(a.astype(np.int32), 256))
-                        for a in arrays)
-            stacked = np.stack([
-                np.concatenate([a.astype(np.int32),
-                                np.full(n_pad - len(a), _I32_MAX,
-                                        np.int32)])
-                for a in arrays])
-            ranks = np.asarray(_ops.multi_merge_ranks(
-                jnp.asarray(stacked), interpret=self.interpret))
-            total = sum(len(a) for a in arrays)
-            # real keys are < INT32_MAX, so every pad ranks after every
-            # real element and real ranks land in [0, total)
-            merged = np.empty(total, dtype=np.int64)
-            for i, a in enumerate(arrays):
-                merged[ranks[i, :len(a)]] = a
+        from repro.kernels import intersect as _isect
+        # every element finds its global rank in the stable k-way merge
+        # in one launch; real keys are < INT32_MAX, so real ranks land
+        # in [0, total) and pad ranks are never read
+        n_pad = _isect.bucket(max(len(a) for a in arrays))
+        stacked = np.full((len(arrays), n_pad), _I32_MAX, np.int32)
+        for i, a in enumerate(arrays):
+            stacked[i, :len(a)] = a
+        ranks = _device("union_k_keys", _isect.multi_merge_ranks, stacked,
+                        interpret=self.interpret)
+        merged = np.empty(sum(len(a) for a in arrays), dtype=np.int64)
+        for i, a in enumerate(arrays):
+            merged[ranks[i, :len(a)]] = a
         keep = np.ones(len(merged), dtype=bool)
         keep[1:] = merged[1:] != merged[:-1]
         return merged[keep]
@@ -421,14 +465,19 @@ class PallasKernels(NumpyKernels):
             return np.full(len(probes), -1, dtype=np.int64)
         if not (_fits_i32(hay) and int(probes.max()) < _I32_MAX
                 and int(probes.min()) >= 0):
+            _delegated("lookup_keys")
             return super().lookup_keys(hay, probes)
-        # probes are sorted, pushed through the skip-ahead intersection
-        # kernel, and unsorted
+        # probes are sorted, pushed through the intersection kernel, and
+        # unsorted
         order = np.argsort(probes, kind="stable")
-        idx_sorted = self.intersect_keys(probes[order], hay)
         idx = np.empty(len(probes), dtype=np.int64)
-        idx[order] = idx_sorted
+        idx[order] = self._isect(probes[order], hay, "lookup_keys")
         return idx
+
+    def segmented_reduce(self, vals, starts, semiring=None,
+                         group_ids=None):
+        _delegated("segmented_reduce")
+        return super().segmented_reduce(vals, starts, semiring, group_ids)
 
 
 # ---------------------------------------------------------------------- #
@@ -445,10 +494,10 @@ ENV_VAR = "REPRO_KERNEL_BACKEND"
 def _make(name: str):
     if name == "numpy":
         return NumpyKernels()
-    if name == "jax-jit":
-        return JaxJitKernels()
-    if name == "pallas-interpret":
-        return PallasKernels(interpret=True)
+    if name not in KERNEL_BACKENDS:
+        raise ValueError(
+            f"unknown kernel backend {name!r}; choose from "
+            f"{KERNEL_BACKENDS} or 'auto'")
     if name == "pallas-tpu":
         import jax
         if jax.default_backend() != "tpu":
@@ -456,28 +505,28 @@ def _make(name: str):
                 "kernel backend 'pallas-tpu' requires a TPU jax backend "
                 f"(found {jax.default_backend()!r}); use "
                 "'pallas-interpret' for CPU validation")
-        return PallasKernels(interpret=False)
-    raise ValueError(
-        f"unknown kernel backend {name!r}; choose from {KERNEL_BACKENDS} "
-        f"or 'auto'")
+    _init_device()
+    if name == "jax-jit":
+        return JaxJitKernels()
+    return PallasKernels(interpret=name == "pallas-interpret")
 
 
-#: why the last ``auto`` probe fell back to numpy (None when it found a
-#: TPU or has not run); surfaced instead of silently swallowed
+#: why the last ``auto`` probe found no TPU (None when it found one or
+#: has not run); surfaced instead of silently swallowed
 AUTO_PROBE_ERROR: Optional[str] = None
 
 
 def _probe_tpu() -> bool:
-    """Is a TPU jax backend available?  Failures are narrowed to the
-    ways a probe can actually fail -- jax missing (ImportError), plugin
-    / runtime initialization broken (RuntimeError), device files
-    unreadable (OSError) -- and the reason is recorded on
-    ``AUTO_PROBE_ERROR`` rather than discarded."""
+    """Is a TPU jax backend available?  A missing jax (ImportError) or
+    unreadable device files (OSError) mean no, with the reason recorded
+    on ``AUTO_PROBE_ERROR``.  A TPU runtime that fails to initialize
+    raises its RuntimeError: resolving ``auto`` to numpy then would
+    hide the chip."""
     global AUTO_PROBE_ERROR
     try:
         import jax
         on_tpu = jax.default_backend() == "tpu"
-    except (ImportError, RuntimeError, OSError) as exc:
+    except (ImportError, OSError) as exc:
         AUTO_PROBE_ERROR = f"{type(exc).__name__}: {exc}"
         return False
     AUTO_PROBE_ERROR = None
@@ -502,9 +551,18 @@ def resolve_kernel_backend(which=None):
 # ---------------------------------------------------------------------- #
 # guarded dispatch: the per-seam degradation chain
 # ---------------------------------------------------------------------- #
-#: degradation order -- each seam call starts at its primary backend's
-#: position in this chain and walks right until one lowering succeeds
-DEGRADATION_CHAIN = ("pallas-tpu", "pallas-interpret", "jax-jit", "numpy")
+#: the rungs below every primary backend: each seam call starts at its
+#: primary and walks right until one lowering succeeds.  The Pallas
+#: interpreter is never a rung: below ``pallas-tpu`` it would run a
+#: kernel Mosaic refused on the host CPU and hide that the chip was not
+#: used.
+FALLBACK_CHAIN = ("jax-jit", "numpy")
+
+
+def degradation_chain(primary: str) -> Tuple[str, ...]:
+    """The degradation chain a seam call walks from ``primary``."""
+    return (primary,) + tuple(b for b in FALLBACK_CHAIN if b != primary)
+
 
 #: the five seam methods the guard mediates
 GUARDED_SEAMS = ("intersect_keys", "union_keys", "union_k_keys",
@@ -752,8 +810,7 @@ class GuardedKernels:
                 raise ValueError(
                     f"unknown kernel backend {primary!r}; choose from "
                     f"{KERNEL_BACKENDS}")
-            start = DEGRADATION_CHAIN.index(primary)
-            self._chain: Tuple = DEGRADATION_CHAIN[start:]
+            self._chain: Tuple = degradation_chain(primary)
             self.name = primary
         else:
             # a raw backend instance: guard it with the numpy oracle as

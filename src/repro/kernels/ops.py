@@ -20,20 +20,16 @@ external callers.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 from repro.kernels import block_sparse_matmul as _bsmm
 from repro.kernels import flash_attention as _fa
 from repro.kernels import intersect as _isect
 from repro.kernels import ssd_chunk as _ssd
-
-_I32_MAX = np.iinfo(np.int32).max
 
 
 def _on_tpu() -> bool:
@@ -50,158 +46,27 @@ def ssd_chunk(x, a, b, c) -> jnp.ndarray:
     return _ssd.ssd_chunk(x, a, b, c, interpret=not _on_tpu())
 
 
-def intersect_sorted(a, b, block: int = 1024) -> jnp.ndarray:
-    return _isect.intersect_sorted(a, b, block=block,
-                                   interpret=not _on_tpu())
+def intersect_sorted(a, b) -> jnp.ndarray:
+    return _isect.intersect_sorted(a, b, interpret=not _on_tpu())
 
 
-def pad_sorted(coords: np.ndarray, multiple: int = 1024) -> np.ndarray:
-    """Pad a sorted int32 coordinate array with INT32_MAX to a block
-    multiple (the kernel's input contract)."""
-    n = len(coords)
-    n_pad = -(-max(n, 1) // multiple) * multiple
-    out = np.full(n_pad, np.iinfo(np.int32).max, np.int32)
-    out[:n] = coords
+def multi_merge_ranks(arrs) -> jnp.ndarray:
+    return _isect.multi_merge_ranks(arrs, interpret=not _on_tpu())
+
+
+def pad_sorted(coords: np.ndarray) -> np.ndarray:
+    """Pad a sorted int32 coordinate array with INT32_MAX to the next
+    power-of-two multiple of the rank kernel's block (the kernels'
+    input contract; one compile per power of two)."""
+    out = np.full(_isect.bucket(len(coords)), np.iinfo(np.int32).max,
+                  np.int32)
+    out[:len(coords)] = coords
     return out
-
-
-# ---------------------------------------------------------------------- #
-# sorted-union / merge kernel (merge-path: one vectorized binary search
-# per output slot, the union dual of the skip-ahead intersection kernel)
-# ---------------------------------------------------------------------- #
-def _merge_kernel(a_ref, b_ref, out_ref, src_ref, *, n: int, m: int,
-                  block: int):
-    a = a_ref[...]                                     # [n] int32 sorted
-    b = b_ref[...]                                     # [m] int32 sorted
-    i_blk = pl.program_id(0)
-    k = i_blk * block + jnp.arange(block, dtype=jnp.int32)   # output slots
-
-    # merge-path partition: i = #elements taken from a among the first k,
-    # found by binary search (ties resolved a-first, i.e. stable merge)
-    lo = jnp.maximum(0, k - m)
-    hi = jnp.minimum(k, n)
-    steps = max(1, (n + m).bit_length())
-
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        j = k - mid - 1
-        av = a[jnp.clip(mid, 0, n - 1)]
-        bv = b[jnp.clip(j, 0, m - 1)]
-        take_more_a = (mid < n) & (j >= 0) & (av <= bv)
-        lo = jnp.where(take_more_a, mid + 1, lo)
-        hi = jnp.where(take_more_a, hi, mid)
-        return lo, hi
-
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    i = lo
-    j = k - i
-    av = a[jnp.clip(i, 0, n - 1)]
-    bv = b[jnp.clip(j, 0, m - 1)]
-    from_a = (i < n) & ((j >= m) | (av <= bv))
-    out_ref[...] = jnp.where(from_a, av, bv)
-    src_ref[...] = jnp.where(from_a, 0, 1).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def merge_sorted(a: jnp.ndarray, b: jnp.ndarray, block: int = 1024,
-                 interpret: bool = False
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Stable merge of two sorted (PAD-padded) int32 arrays.
-
-    Returns (merged [n+m], src [n+m]) where src is 0 for elements taken
-    from ``a`` and 1 for ``b``; on equal values ``a`` comes first."""
-    n, = a.shape
-    m, = b.shape
-    total = n + m
-    block = min(block, total)
-    grid = (pl.cdiv(total, block),)
-    return pl.pallas_call(
-        functools.partial(_merge_kernel, n=n, m=m, block=block),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((m,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((total,), jnp.int32),
-                   jax.ShapeDtypeStruct((total,), jnp.int32)],
-        interpret=interpret,
-    )(a, b)
-
-
-# ---------------------------------------------------------------------- #
-# k-ary multi-merge kernel: each element of each of the k sorted input
-# rows finds its global rank in the merged stream with k-1 vectorized
-# binary searches (stable: ties resolve by row index).  The union dual
-# of stacking pairwise merge-path calls, in one launch.
-# ---------------------------------------------------------------------- #
-def _multi_merge_kernel(arrs_ref, rank_ref, *, k: int, n: int, block: int):
-    a_all = arrs_ref[...]                              # [k, n] int32 sorted
-    i = pl.program_id(0)                               # which row
-    jb = pl.program_id(1)                              # which block
-    e = jax.lax.dynamic_slice(a_all, (i, jb * block), (1, block))[0]
-    own = jb * block + jnp.arange(block, dtype=jnp.int32)
-    total = own                                        # own stable position
-    steps = max(1, n.bit_length())
-
-    for jj in range(k):                                # static unroll over rows
-        row = a_all[jj]
-
-        def search(inclusive: bool):
-            lo = jnp.zeros(e.shape, jnp.int32)
-            hi = jnp.full(e.shape, n, jnp.int32)
-
-            def body(_, carry):
-                lo, hi = carry
-                mid = (lo + hi) // 2
-                rv = row[jnp.clip(mid, 0, n - 1)]
-                # freeze once converged (lo == hi) so the fixed-step
-                # loop cannot overshoot past n
-                go_right = (lo < hi) & (rv <= e if inclusive else rv < e)
-                lo = jnp.where(go_right, mid + 1, lo)
-                hi = jnp.where(go_right, hi, mid)
-                return lo, hi
-
-            lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-            return lo
-
-        cnt_le = search(True)                          # elements <= e
-        cnt_lt = search(False)                         # elements <  e
-        contrib = jnp.where(jj < i, cnt_le, jnp.where(jj > i, cnt_lt, 0))
-        total = total + contrib
-    rank_ref[...] = total[None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def multi_merge_ranks(arrs: jnp.ndarray, block: int = 256,
-                      interpret: bool = False) -> jnp.ndarray:
-    """arrs: [k, n] int32, each row sorted and PAD-padded.  Returns the
-    [k, n] global rank of every element in the stable k-way merge
-    (pad ranks are meaningless; callers slice to the real lengths)."""
-    k, n = arrs.shape
-    block = min(block, n)
-    grid = (k, pl.cdiv(n, block))
-    return pl.pallas_call(
-        functools.partial(_multi_merge_kernel, k=k, n=n, block=block),
-        grid=grid,
-        in_specs=[pl.BlockSpec((k, n), lambda i, j: (0, 0))],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, n), jnp.int32),
-        interpret=interpret,
-    )(arrs)
 
 
 # ---------------------------------------------------------------------- #
 # offset-keyed co-iteration primitives (vector backend entry points)
 # ---------------------------------------------------------------------- #
-def _fits_i32(a: np.ndarray) -> bool:
-    return len(a) == 0 or int(a[-1]) < _I32_MAX
-
-
 def _kb():
     """The process-default kernel backend (env-resolved per call, so
     tests may flip ``$REPRO_KERNEL_BACKEND`` between calls)."""
@@ -214,8 +79,8 @@ def intersect_keys(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     key arrays; keys unique per array), -1 where absent.
 
     Dispatches to the active kernel backend: numpy ``searchsorted``,
-    a jitted XLA binary search, or the Pallas skip-ahead intersection
-    kernel (int32 key domain)."""
+    a jitted XLA binary search, or the Pallas rank kernel (int32 key
+    domain)."""
     return _kb().intersect_keys(a, b)
 
 
@@ -225,7 +90,7 @@ def union_keys(a: np.ndarray, b: np.ndarray
     array).  Returns (union, pos_a, pos_b): for every union element its
     position in ``a`` / ``b`` or -1.
 
-    Pallas backends run the merge-path kernel + host dedup."""
+    Pallas backends run the merge-rank kernel + host dedup."""
     return _kb().union_keys(a, b)
 
 
@@ -234,9 +99,9 @@ def union_k_keys(arrays) -> Tuple[np.ndarray, list]:
     array).  Returns (union, [pos_i]): for every union element its
     position in array i, or -1 where absent.
 
-    k == 2 delegates to ``union_keys``; larger fan-ins run the k-ary
-    ``multi_merge_ranks`` Pallas kernel on the pallas backends and a
-    concatenate-and-unique ``searchsorted`` lowering on numpy."""
+    The pallas backends rank every element in the stable k-way merge
+    with the ``multi_merge_ranks`` kernel (any k); numpy runs a
+    concatenate-and-unique ``searchsorted`` lowering."""
     return _kb().union_k_keys(arrays)
 
 
@@ -245,9 +110,8 @@ def lookup_keys(hay: np.ndarray, probes: np.ndarray) -> np.ndarray:
     int64, unique) of every ``probes`` element (arbitrary order,
     duplicates fine), -1 where absent.
 
-    Pallas backends sort the probes, push them through the skip-ahead
-    intersection kernel, and unsort; numpy is one vectorized
-    ``searchsorted``."""
+    Pallas backends sort the probes, push them through the intersection
+    kernel, and unsort; numpy is one vectorized ``searchsorted``."""
     return _kb().lookup_keys(hay, probes)
 
 

@@ -18,14 +18,18 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(dp: int, tp: int, pods: int = 1) -> Mesh:
     """Arbitrary (pod) x data x model mesh (smoke tests use 1x1)."""
+    auto = jax.sharding.AxisType.Auto
     if pods > 1:
-        return jax.make_mesh((pods, dp, tp), ("pod", "data", "model"))
-    return jax.make_mesh((dp, tp), ("data", "model"))
+        return jax.make_mesh((pods, dp, tp), ("pod", "data", "model"),
+                             axis_types=(auto,) * 3)
+    return jax.make_mesh((dp, tp), ("data", "model"),
+                         axis_types=(auto,) * 2)
 
 
 def mesh_axis_sizes(mesh) -> dict:

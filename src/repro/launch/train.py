@@ -36,7 +36,8 @@ def main() -> None:
                          checkpoint_dir=args.ckpt_dir,
                          global_batch=args.batch, seq_len=args.seq,
                          accum_steps=args.accum)
-    mesh = jax.make_mesh((args.dp, args.tp), ("data", "model"))
+    mesh = jax.make_mesh((args.dp, args.tp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     trainer = Trainer(cfg, tcfg, mesh=mesh)
     state = trainer.run_with_recovery()
     print(f"finished at step {state.step}")

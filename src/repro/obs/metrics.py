@@ -6,6 +6,11 @@ prefix, ``/``-separated label suffix --
     kernel.seam_seconds/<seam>/<backend>     histogram (seam latency)
     kernel.downgrade/<action>                counter   (retry/downgrade/
                                                         demote/unavailable)
+    kernel.device_call/<seam>                counter   (programs launched
+                                                        on the JAX device)
+    kernel.host_delegation/<seam>            counter   (device-backend
+                                                        calls handed to
+                                                        numpy)
     guards.violation/<check>                 counter
     vector.stage_seconds/<stage>             counter   (float seconds)
     dse.point/<status>                       counter   (ok/restored/...)

@@ -61,7 +61,9 @@ class Trainer:
                  optimizer: Optional[opt.Optimizer] = None):
         self.cfg = cfg
         self.tcfg = tcfg
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or jax.make_mesh(
+            (1, 1), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         self.optimizer = optimizer or opt.for_config(cfg)
         self.ckpt = CheckpointManager(tcfg.checkpoint_dir,
                                       keep=tcfg.keep_checkpoints)

@@ -170,6 +170,33 @@ def test_process_sweep_bitwise_identical_to_serial(rng):
     assert _objectives(serial) == _objectives(sharded)
 
 
+def test_process_sweep_on_tpu_host_spawns_workers_off_the_chip(
+        rng, monkeypatch):
+    """On a TPU host the parent holds the chip: sweep workers start
+    with spawn and see JAX_PLATFORMS=cpu and the numpy kernels, and
+    the sharded sweep still matches the serial one bit for bit."""
+    import os
+
+    import jax
+    from repro.dse import engine
+    inputs, shapes = _workload(rng, n=16)
+    pts = _space((0.01, 1.0)).grid()
+    serial = SweepEngine(inputs, shapes, backend="analytic").sweep(pts)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the workers' settings come from the pool, not from this process
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    sharded = SweepEngine(inputs, shapes, backend="analytic",
+                          executor="process", max_workers=2).sweep(pts)
+    assert _objectives(serial) == _objectives(sharded)
+    with engine._worker_pool(1, (inputs, shapes, {"backend": "analytic"},
+                                 None)) as pool:
+        assert pool._mp_context.get_start_method() == "spawn"
+        seen = [pool.submit(os.getenv, k).result(timeout=120)
+                for k in ("JAX_PLATFORMS", "REPRO_KERNEL_BACKEND")]
+    assert seen == ["cpu", "numpy"]
+
+
 def test_process_sweep_worker_crash_checkpoint_resume(rng, tmp_path):
     """PR-8 contract across the worker boundary: a worker killed by an
     injected crash loses only its in-flight chunk; the parent persists

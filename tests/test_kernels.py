@@ -132,7 +132,7 @@ def test_ssd_kernel_inside_model_path():
 # sorted-coordinate intersection (ExTensor skip-ahead -> TPU)
 # ---------------------------------------------------------------------- #
 ISECT_CASES = [
-    # (n_a, n_b, overlap_frac, block)
+    # (n_a, n_b, overlap_frac, trailing pads carried in A)
     (100, 400, 0.5, 64),
     (1000, 1000, 0.1, 256),
     (64, 2048, 0.9, 64),
@@ -141,8 +141,8 @@ ISECT_CASES = [
 ]
 
 
-@pytest.mark.parametrize("na,nb,frac,block", ISECT_CASES)
-def test_intersect_sorted_sweep(na, nb, frac, block):
+@pytest.mark.parametrize("na,nb,frac,pads", ISECT_CASES)
+def test_intersect_sorted_sweep(na, nb, frac, pads):
     rng = np.random.default_rng(7)
     universe = rng.choice(10 * (na + nb) + 10, size=na + nb,
                           replace=False)
@@ -154,10 +154,11 @@ def test_intersect_sorted_sweep(na, nb, frac, block):
     a = np.sort(np.asarray(a_vals, np.int32)) if a_vals else \
         np.zeros((0,), np.int32)
 
-    ap = ops.pad_sorted(a, block)
-    bp = ops.pad_sorted(b, max(len(b), 8))
+    ap = ops.pad_sorted(np.concatenate(
+        [a, np.full(pads, np.iinfo(np.int32).max, np.int32)]))
+    bp = ops.pad_sorted(b)
     got = np.asarray(ops.intersect_sorted(jnp.asarray(ap),
-                                          jnp.asarray(bp), block=block))
+                                          jnp.asarray(bp)))
     want = np.asarray(ref.intersect_sorted_ref(ap, bp))
     np.testing.assert_array_equal(got, want)
     # semantic check: every hit points at the right coordinate
@@ -166,6 +167,25 @@ def test_intersect_sorted_sweep(na, nb, frac, block):
             assert bp[got[i]] == ap[i]
         else:
             assert ap[i] not in b
+
+
+def test_rank_sorted_windows_and_grid_chunks(monkeypatch):
+    """Windows spanning several B tiles, an all-pad A block, and a grid
+    split into several launches (``MAX_GRID``) still count exactly."""
+    from repro.kernels import intersect as isect
+    monkeypatch.setattr(isect, "MAX_GRID", 2)
+    rng = np.random.default_rng(5)
+    a = np.sort(rng.choice(1 << 20, size=3000, replace=False))
+    b = np.sort(rng.choice(1 << 20, size=8000, replace=False))
+    ap, bp = ops.pad_sorted(a.astype(np.int32)), \
+        ops.pad_sorted(b.astype(np.int32))
+    lt, eq = isect.rank_sorted(jnp.asarray(ap), jnp.asarray(bp),
+                               interpret=True)
+    np.testing.assert_array_equal(np.asarray(lt)[:len(a)],
+                                  np.searchsorted(b, a, side="left"))
+    np.testing.assert_array_equal(
+        np.asarray(eq)[:len(a)],
+        np.searchsorted(b, a, side="right") - np.searchsorted(b, a))
 
 
 def test_intersect_matches_fibertree_intersection():
@@ -179,10 +199,10 @@ def test_intersect_matches_fibertree_intersection():
     fb = Fiber(list(map(int, b_c)), [1.0] * len(b_c))
     want = {c for c, _, _ in fa.intersect(fb)}
 
-    ap = ops.pad_sorted(a_c, 64)
-    bp = ops.pad_sorted(b_c, 64)
+    ap = ops.pad_sorted(a_c)
+    bp = ops.pad_sorted(b_c)
     idx = np.asarray(ops.intersect_sorted(jnp.asarray(ap),
-                                          jnp.asarray(bp), block=64))
+                                          jnp.asarray(bp)))
     got = {int(ap[i]) for i in range(len(a_c)) if idx[i] >= 0}
     assert got == want
 
@@ -216,22 +236,22 @@ def test_union_k_keys_matches_reference(k, sizes):
         assert not np.isin(u[~hit], a).any()
 
 
-@pytest.mark.parametrize("k,n,block", [(3, 64, 32), (4, 100, 64),
+@pytest.mark.parametrize("k,n,scale", [(3, 64, 32), (4, 100, 64),
                                        (2, 256, 128), (6, 33, 16)])
-def test_multi_merge_ranks_interpret(k, n, block):
+def test_multi_merge_ranks_interpret(k, n, scale):
     """The Pallas k-way merge-rank kernel (interpret mode) agrees with
-    the stable numpy merge."""
+    the stable numpy merge; ``scale`` spreads the keys over a wider
+    range."""
     rng = np.random.default_rng(17)
     rows = [np.sort(rng.choice(5000, size=rng.integers(1, n),
-                               replace=False)).astype(np.int32)
+                               replace=False) * scale).astype(np.int32)
             for _ in range(k)]
-    n_pad = max(len(ops.pad_sorted(r, block)) for r in rows)
+    n_pad = max(len(ops.pad_sorted(r)) for r in rows)
     stacked = np.stack([
         np.concatenate([r, np.full(n_pad - len(r),
                                    np.iinfo(np.int32).max, np.int32)])
         for r in rows])
-    ranks = np.asarray(ops.multi_merge_ranks(jnp.asarray(stacked),
-                                             block=block, interpret=True))
+    ranks = np.asarray(ops.multi_merge_ranks(jnp.asarray(stacked)))
     total = sum(len(r) for r in rows)
     merged = np.empty(total, dtype=np.int64)
     for i, r in enumerate(rows):
@@ -394,12 +414,11 @@ def test_multi_merge_ranks_adversarial(k, n_max):
             r = np.sort(rng.choice(5000, size=rng.integers(1, n_max),
                                    replace=False))
         rows.append(r.astype(np.int32))
-    n_pad = max(int(np.ceil(max(len(r) for r in rows) / 64)) * 64, 64)
+    n_pad = len(ops.pad_sorted(max(rows, key=len)))
     stacked = np.stack([
         np.concatenate([r, np.full(n_pad - len(r), hi, np.int32)])
         for r in rows])
-    ranks = np.asarray(ops.multi_merge_ranks(jnp.asarray(stacked),
-                                             block=64, interpret=True))
+    ranks = np.asarray(ops.multi_merge_ranks(jnp.asarray(stacked)))
     total = sum(len(r) for r in rows)
     merged = np.empty(total, dtype=np.int64)
     for i, r in enumerate(rows):
@@ -419,3 +438,112 @@ def test_env_var_selects_backend(monkeypatch):
     assert kbk.resolve_kernel_backend("numpy").name == "numpy"
     with pytest.raises(Exception):
         kbk.resolve_kernel_backend("no-such-backend")
+
+
+# ---------------------------------------------------------------------- #
+# no fallback that hides the device
+# ---------------------------------------------------------------------- #
+def test_pallas_tpu_chain_has_no_interpreter_rung():
+    assert kbk.GuardedKernels("pallas-tpu").chain_names == \
+        ("pallas-tpu", "jax-jit", "numpy")
+    assert "pallas-interpret" not in kbk.degradation_chain("pallas-tpu")
+
+
+def test_probe_tpu_propagates_runtime_init_failure(monkeypatch):
+    """A TPU runtime that fails to initialize is an error, not a quiet
+    resolution of ``auto`` to numpy."""
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(jax, "default_backend", broken)
+    monkeypatch.delenv(kbk.ENV_VAR, raising=False)
+    with pytest.raises(RuntimeError, match="initialize"):
+        kbk._probe_tpu()
+    with pytest.raises(RuntimeError, match="initialize"):
+        kbk.resolve_guarded_kernels()
+
+
+@pytest.mark.parametrize("name", ["pallas-interpret", "jax-jit"])
+def test_host_delegation_counted_per_seam(name):
+    """Keys beyond the device lowering's domain go to numpy, counted on
+    ``kernel.host_delegation/<seam>``; in-domain calls count as device
+    calls instead."""
+    from repro.obs.metrics import metrics
+    kb = kbk.resolve_kernel_backend(name)
+    reg = metrics()
+
+    def count(kind, seam):
+        return reg.counter(f"kernel.{kind}/{seam}").value
+
+    rng = np.random.default_rng(3)
+    packed = _keys(rng, (1 << 62) - 2000, (1 << 62) - 1, 200)
+    small = _keys(rng, 0, 500, 200)
+    if name == "pallas-interpret":
+        d0, h0 = count("device_call", "intersect_keys"), \
+            count("host_delegation", "intersect_keys")
+        kb.intersect_keys(packed, packed[::2])
+        assert count("host_delegation", "intersect_keys") == h0 + 1
+        kb.intersect_keys(small, small[::2])
+        assert count("device_call", "intersect_keys") == d0 + 1
+        assert count("host_delegation", "intersect_keys") == h0 + 1
+        h0 = count("host_delegation", "union_k_keys")
+        kb.union_k_keys([packed, packed[::3], packed[1::3]])
+        assert count("host_delegation", "union_k_keys") == h0 + 1
+    else:
+        # probes at the int64 pad sentinel are beyond the jitted search
+        h0 = count("host_delegation", "lookup_keys")
+        kb.lookup_keys(packed, np.array([np.iinfo(np.int64).max]))
+        assert count("host_delegation", "lookup_keys") == h0 + 1
+    h0 = count("host_delegation", "segmented_reduce")
+    vals = np.ones(6)
+    kb.segmented_reduce(vals, np.array([0, 2, 5]), Semiring.or_and())
+    assert count("host_delegation", "segmented_reduce") == h0 + 1
+
+
+def test_jax_jit_reductions_stay_on_host_on_a_tpu(monkeypatch):
+    """TPU float64 is emulated (inexact on a v5e), so jax-jit's f64
+    segmented reductions delegate to numpy there, counted."""
+    from repro.obs.metrics import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kb = kbk.JaxJitKernels()
+    assert not kb.f64_exact
+    rng = np.random.default_rng(4)
+    vals = rng.random(50)
+    starts = np.array([0, 10, 31])
+    c = metrics().counter("kernel.host_delegation/segmented_reduce")
+    d = metrics().counter("kernel.device_call/segmented_reduce")
+    h0, d0 = c.value, d.value
+    for sr in (Semiring.arithmetic(), Semiring.min_plus()):
+        np.testing.assert_array_equal(
+            kb.segmented_reduce(vals, starts, sr),
+            kbk.NumpyKernels().segmented_reduce(vals, starts, sr))
+    assert (c.value, d.value) == (h0 + 2, d0)
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins; otherwise the cache is the
+    fixed in-checkout directory.  Only a TPU host configures either."""
+    from pathlib import Path
+    repo = Path(__file__).resolve().parent.parent
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert kbk.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert kbk.compile_cache_dir() == str(repo / ".jax_cache")
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        kbk._init_device.__wrapped__()          # CPU: sets nothing
+        assert {k: getattr(jax.config, k) for k in keys} == saved
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kbk._init_device.__wrapped__()
+        assert jax.config.jax_compilation_cache_dir == \
+            str(repo / ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        jax.config.update(keys[0], saved[keys[0]])
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        kbk._init_device.__wrapped__()          # JAX reads the variable
+        assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

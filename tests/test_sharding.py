@@ -1,4 +1,5 @@
 """Sharding rules + the TeAAL mapping->PartitionSpec compiler."""
+import os
 import subprocess
 import sys
 
@@ -38,7 +39,7 @@ def test_param_pspec_indivisible_stays_replicated():
 
 
 def test_embedding_path_aware():
-    mesh = jax.sharding.AbstractMesh((("data", 4), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((4, 4), ("data", "model"))
     params = {"embed": {"tok": jnp.zeros((1024, 64))},
               "blocks": {"w": jnp.zeros((64, 256))}}
     specs = S.param_pspecs(params, mesh)
@@ -47,7 +48,7 @@ def test_embedding_path_aware():
 
 
 def test_divisibility_fallback_in_rules():
-    mesh = jax.sharding.AbstractMesh((("data", 4), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((4, 4), ("data", "model"))
     rules = AxisRules({"batch": ("data",), "heads": ("model",)})
     # 6 heads % 4 != 0 -> replicated, batch 8 % 4 == 0 -> sharded
     sp = spec_for((8, 6), ("batch", "heads"), mesh=mesh)
@@ -90,7 +91,7 @@ def test_compile_mapping_unbound_spatial_rank_raises():
 # ---------------------------------------------------------------------- #
 def test_cache_pspecs_shard_kv_seq():
     import repro.configs as C
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     cfg = C.get_smoke("qwen3-14b")
     specs = S.cache_pspecs(cfg, batch=4, max_len=64, mesh=mesh)
     # [L, b, s, kv, h]: batch over pod(data), seq over (data, model)
@@ -111,7 +112,8 @@ from repro.sharding import logical
 import dataclasses
 
 cfg = dataclasses.replace(C.get_smoke("olmo-1b"), scan_layers=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 logical.set_mesh(mesh); logical.set_rules(S.rules_for("train"))
 step = ST.make_train_step(cfg)
 import repro.optim.optimizers as opt
@@ -149,5 +151,8 @@ def test_multi_device_train_step_compiles():
     r = subprocess.run([sys.executable, "-c", SUBPROC],
                        capture_output=True, text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                            "HOME": os.environ.get("HOME", ""),
+                            # the child stays off any accelerator: the
+                            # chip belongs to one process at a time
+                            "JAX_PLATFORMS": "cpu"})
     assert "SUBPROCESS_OK" in r.stdout, r.stderr[-2000:]
