@@ -20,6 +20,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.spans import maybe_span
+
 from .cascade import CascadeDAG
 from .components import PerformanceModel
 from .einsum import Semiring
@@ -247,10 +249,30 @@ class CascadeSimulator:
         return ("two_finger", None)
 
     # ------------------------------------------------------------------ #
+    def _merge_work(self, out_name: str, inputs: Sequence[str],
+                    store: Dict[str, FTensor], shapes: Dict[str, int]
+                    ) -> List[Tuple[str, int, int]]:
+        """(tensor, elements, lists) of the merger work that the online
+        rank swizzles of this Einsum's intermediate inputs need."""
+        plan = self.plans[out_name]
+        estimate = getattr(self.backend, "merge_estimate", None)
+        out: List[Tuple[str, int, int]] = []
+        for t in inputs:
+            if not self.dag.is_intermediate(t):
+                continue
+            order = _innermost_var_order(plan, t)
+            stored_ranks = list(store[t].ranks)
+            p = merge_prefix([r.lower() for r in stored_ranks], order)
+            if p is None:
+                continue
+            events = merge_events(store[t], order)
+            if not events and estimate is not None:
+                events = estimate(t, stored_ranks, p, shapes) or []
+            out += [(t, elements, lists) for elements, lists in events]
+        return out
+
     def run(self, inputs: Dict[str, Any],
             var_shapes: Optional[Dict[str, int]] = None) -> SimResult:
-        from repro.obs.spans import maybe_span
-
         with maybe_span("cascade:" + (self.spec.name or "cascade"),
                         "cascade",
                         {"backend": getattr(self.backend, "name", "?")}):
@@ -305,9 +327,10 @@ class CascadeSimulator:
                     v = r.lower()
                     if v in shapes:
                         decl_shapes[r] = shapes[v]
-                store[o_name] = restore_declared(
-                    out_exec, self.plans[o_name], declared_order,
-                    decl_shapes)
+                with maybe_span("gen:restore", "gen"):
+                    store[o_name] = restore_declared(
+                        out_exec, self.plans[o_name], declared_order,
+                        decl_shapes)
             pending.clear()
             pending_out.clear()
             shapes = self._var_shapes(store, var_shapes)
@@ -343,31 +366,23 @@ class CascadeSimulator:
                 need_data = prepare(plan,
                                     {t: store[t] for t in e.input_names},
                                     shapes)
-            exec_forms = (self.resolver.transform_all(
-                out_name, {t: store[t] for t in e.input_names})
-                if need_data else {})
+            with maybe_span("gen:transform", "gen"):
+                exec_forms = (self.resolver.transform_all(
+                    out_name, {t: store[t] for t in e.input_names})
+                    if need_data else {})
+                merges = self._merge_work(out_name, e.input_names, store,
+                                          shapes)
+                out_initial = None
+                if out_name in store:
+                    # update-in-place semantics (e.g. GraphDynS
+                    # filtered write)
+                    out_initial = self.resolver.transform_tensor(
+                        out_name, store[out_name])
 
-            # online rank swizzles of intermediates -> merger work
-            estimate = getattr(self.backend, "merge_estimate", None)
-            for t in e.input_names:
-                if not self.dag.is_intermediate(t):
-                    continue
-                order = _innermost_var_order(plan, t)
-                stored_ranks = list(store[t].ranks)
-                p = merge_prefix([r.lower() for r in stored_ranks], order)
-                if p is None:
-                    continue
-                events = merge_events(store[t], order)
-                if not events and estimate is not None:
-                    events = estimate(t, stored_ranks, p, shapes) or []
-                for elements, lists in events:
-                    self.instr.merge(out_name, t, elements, lists)
-
-            out_initial = None
-            if out_name in store:
-                # update-in-place semantics (e.g. GraphDynS filtered write)
-                out_initial = self.resolver.transform_tensor(
-                    out_name, store[out_name])
+            if merges:
+                with maybe_span("model:intake", "model"):
+                    for t, elements, lists in merges:
+                        self.instr.merge(out_name, t, elements, lists)
 
             if self.model is not None and exec_forms:
                 self.model.register_exec_tensors(out_name, exec_forms)
@@ -381,8 +396,10 @@ class CascadeSimulator:
             pending_out.append(out_name)
         flush()
 
-        report = (evaluate(self.spec, self.plans, self.model)
-                  if self.model is not None else None)
+        report = None
+        if self.model is not None:
+            with maybe_span("model:evaluate", "model"):
+                report = evaluate(self.spec, self.plans, self.model)
         if report is not None:
             report.fallback_reasons = dict(fallbacks)
             report.downgrade_events = dict(downgrades)

@@ -43,8 +43,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.obs.metrics import metrics as _obs_metrics
-from repro.obs.spans import active_tracer
+from repro.obs.spans import NULL_SPAN, active_tracer, maybe_span
 
 from .csf import CSF, _from_sorted_points
 from .einsum import BinOp, Semiring, Take, TensorAccess
@@ -70,10 +69,62 @@ DENSE_GROUP_CAP = 1 << 25
 
 _I32_N = 1 << 31
 
-#: pipeline-stage order used when synthesizing stage spans from the
-#: accumulated profile timers (matches the stage_times key set)
-STAGE_ORDER = ("materialize", "pair-merge", "lookup", "finalize",
-               "reduce", "output-build")
+
+class _StageClock:
+    """Exclusive per-stage wall time of one execution.
+
+    Stages nest (finalize encloses reduce and whole levels, a level's
+    materialize encloses its pair-merges and lookups): entering a stage
+    ends the enclosing stage's current piece and leaving it starts a
+    new one, so each moment is charged to one stage only.  With a
+    tracer, each piece is a real ``stage:<name>`` span, and the timer
+    takes the span's own duration, so the spans of one thread never
+    overlap and sum to the timers."""
+
+    __slots__ = ("times", "tracer", "einsum", "_open", "_t0", "_span")
+
+    def __init__(self, times: Counter, tracer, einsum: str):
+        self.times = times
+        self.tracer = tracer
+        self.einsum = einsum
+        self._open: List[str] = []
+        self._t0 = 0.0
+        self._span = None
+
+    def _begin(self) -> None:
+        if self.tracer is None:
+            self._t0 = time.perf_counter()
+            return
+        self._span = self.tracer.span("stage:" + self._open[-1], "stage",
+                                      {"einsum": self.einsum})
+        self._span.__enter__()
+
+    def _end(self) -> None:
+        sp = self._span
+        if sp is None:
+            self.times[self._open[-1]] += time.perf_counter() - self._t0
+            return
+        sp.__exit__(None, None, None)
+        self._span = None
+        self.times[self._open[-1]] += sp.dur_us / 1e6
+
+    def enter(self, stage: str) -> None:
+        if self._open:
+            self._end()
+        self._open.append(stage)
+        self._begin()
+
+    def exit(self) -> None:
+        self._end()
+        self._open.pop()
+        if self._open:
+            self._begin()
+
+    def close(self) -> None:
+        """End every open stage (an execution left by an exception)."""
+        if self._open:
+            self._end()
+            self._open.clear()
 
 
 # ---------------------------------------------------------------------- #
@@ -419,6 +470,9 @@ class VectorBackend(ExecutorBackend):
         #: 'reduce' / 'output-build'), reset per execute()/execute_csf()
         self.profile = profile
         self.stage_times: Counter = Counter()
+        #: the stage clock of the running execution (None unless
+        #: profiling or tracing)
+        self._stages: Optional[_StageClock] = None
 
     # ------------------------------------------------------------------ #
     @property
@@ -430,18 +484,15 @@ class VectorBackend(ExecutorBackend):
 
     @contextmanager
     def _einsum_telemetry(self, name: str):
-        """``einsum:<name>`` span plus synthetic stage sub-spans
-        around one execution; yields ``None`` (and does nothing) when
-        no tracer is installed.
+        """``einsum:<name>`` span around one execution, with the stage
+        clock running inside it; yields ``None`` (and times no stage
+        unless ``profile``) when no tracer is installed.
 
-        While active it forces stage profiling on so the existing
-        profile timers feed the trace, and tags the guarded kernel
-        dispatch with the Einsum name so seam spans and
-        ``DowngradeEvent``\\ s carry their attribution.  On exit the
-        accumulated per-stage seconds become one ``stage:<stage>``
-        span each, laid consecutively inside the einsum span's window
-        (aggregates, not real intervals -- marked ``synthetic``) and
-        added to the ``vector.stage_seconds/*`` counters.
+        It resets ``stage_times``, and tags the guarded kernel dispatch
+        with the Einsum name so seam spans and ``DowngradeEvent``\\ s
+        carry their attribution.  While a tracer is installed the
+        stages are timed whatever ``profile`` says, each piece a
+        ``stage:<stage>`` span (:class:`_StageClock`).
 
         The Einsum tag on the kernel dispatch is set regardless of
         tracing (one attribute write): a ``DowngradeEvent`` recorded
@@ -450,38 +501,36 @@ class VectorBackend(ExecutorBackend):
         tag = hasattr(self.kernels, "current_einsum")
         if tag:
             self.kernels.current_einsum = name
+        self.stage_times = Counter()
         tr = active_tracer()
         if tr is None:
+            self._stages = (_StageClock(self.stage_times, None, name)
+                            if self.profile else None)
             try:
                 yield None
             finally:
+                self._end_stages()
                 if tag:
                     self.kernels.current_einsum = prev_einsum
             return
-        prev_profile = self.profile
-        self.profile = True
-        snap = Counter(self.stage_times)
+        self._stages = _StageClock(self.stage_times, tr, name)
         sp = tr.span(f"einsum:{name}", cat="einsum",
                      args={"backend": self.name})
         try:
             with sp:
-                yield sp
+                try:
+                    yield sp
+                finally:
+                    # open stage spans end before their einsum does
+                    self._end_stages()
         finally:
-            self.profile = prev_profile
             if tag:
                 self.kernels.current_einsum = prev_einsum
-            reg = _obs_metrics()
-            cursor = sp._start_us
-            for stage in STAGE_ORDER:
-                secs = float(self.stage_times[stage]) - float(snap[stage])
-                if secs <= 0.0:
-                    continue
-                reg.counter(f"vector.stage_seconds/{stage}").inc(secs)
-                dur_us = secs * 1e6
-                tr.add_span(f"stage:{stage}", "stage", cursor, dur_us,
-                            {"einsum": name, "parent": f"einsum:{name}",
-                             "synthetic": True})
-                cursor += dur_us
+
+    def _end_stages(self) -> None:
+        if self._stages is not None:
+            self._stages.close()
+            self._stages = None
 
     # ------------------------------------------------------------------ #
     def execute(self, plan, tensors, var_shapes, semiring=None, instr=None,
@@ -489,29 +538,39 @@ class VectorBackend(ExecutorBackend):
                 isect_leader=None) -> FTensor:
         instr = instr or NullInstr()
         semiring = semiring or Semiring.arithmetic()
-        self.stage_times = Counter()
         with self._einsum_telemetry(plan.output) as sp:
             try:
-                vp = lower(plan, var_shapes, semiring, out_initial,
-                           isect_strategy, isect_leader)
-                csf = {}
-                for a in vp.accs:
-                    v = tensors[a.tensor]
-                    csf[a.tensor] = v if isinstance(v, CSF) else \
-                        CSF.from_ftensor(v)
-                init_csf = None
-                if out_initial is not None:
-                    init_csf = out_initial if isinstance(out_initial, CSF) \
-                        else CSF.from_ftensor(out_initial)
+                with (NULL_SPAN if sp is None else
+                      sp.tracer.span("vec:lower", "vec")):
+                    vp = lower(plan, var_shapes, semiring, out_initial,
+                               isect_strategy, isect_leader)
+                with (NULL_SPAN if sp is None else
+                      sp.tracer.span("vec:to_csf", "vec")):
+                    csf = {}
+                    for a in vp.accs:
+                        v = tensors[a.tensor]
+                        csf[a.tensor] = v if isinstance(v, CSF) else \
+                            CSF.from_ftensor(v)
+                    init_csf = None
+                    if out_initial is not None:
+                        init_csf = out_initial \
+                            if isinstance(out_initial, CSF) \
+                            else CSF.from_ftensor(out_initial)
                 csf_out, _ = self._run(vp, plan, csf, instr,
                                        out_initial=init_csf)
                 self.last_path = "vector"
                 self.last_fallback_reason = None
                 self.last_downgrades = self._drain_downgrades()
-                if sp is not None:
-                    sp.set("path", "vector")
-                return csf_out.to_ftensor()
+                if sp is None:
+                    return csf_out.to_ftensor()
+                sp.set("path", "vector")
+                with sp.tracer.span("vec:to_ftensor", "vec"):
+                    return csf_out.to_ftensor()
             except Exception as exc:
+                # stages the fault left open end here, not after the
+                # oracle's re-run
+                if self._stages is not None:
+                    self._stages.close()
                 if not (self.fallback and self._isolates(exc)):
                     self.last_downgrades = self._drain_downgrades()
                     raise
@@ -622,16 +681,17 @@ class VectorBackend(ExecutorBackend):
         throughput benchmark."""
         instr = instr or NullInstr()
         semiring = semiring or Semiring.arithmetic()
-        self.stage_times = Counter()
-        with self._einsum_telemetry(plan.output):
+        with self._einsum_telemetry(plan.output) as sp:
             shapes = dict(var_shapes or {})
             for c in tensors.values():
                 for r, s in getattr(c, "rank_shapes", {}).items():
                     if isinstance(s, int):
                         v = r.lower()
                         shapes[v] = max(shapes.get(v, 0), s)
-            vp = lower(plan, shapes, semiring, None, isect_strategy,
-                       isect_leader)
+            with (NULL_SPAN if sp is None else
+                  sp.tracer.span("vec:lower", "vec")):
+                vp = lower(plan, shapes, semiring, None, isect_strategy,
+                           isect_leader)
             exec_csf = prepare_csf_inputs(plan, tensors)
             return self._run(vp, plan, exec_csf, instr)
 
@@ -685,14 +745,11 @@ class VectorBackend(ExecutorBackend):
             inner = n_levels - 1 if fuse is not None else n_levels
             for li in range(1, inner):
                 part = self._level(li, vp, csf, part, counts)
-            tf = time.perf_counter() if self.profile else 0.0
-            # other stage counters can also advance inside this window
-            # (reduce always; a declined fuse re-enters _level, charging
-            # materialize/pair-merge/lookup) -- net their deltas out so
-            # the per-stage breakdown stays non-overlapping
-            inner_keys = ("reduce", "materialize", "pair-merge", "lookup")
-            s0 = sum(float(self.stage_times[k]) for k in inner_keys) \
-                if self.profile else 0.0
+            # reduce, and a declined fuse's re-entered _level, are
+            # stages of their own inside finalize
+            stages = self._stages
+            if stages is not None:
+                stages.enter("finalize")
             pv = None
             if fuse is not None:
                 # batched innermost level: one wide expand-multiply-
@@ -703,16 +760,16 @@ class VectorBackend(ExecutorBackend):
                 if fuse is not None:
                     part = self._level(n_levels - 1, vp, csf, part, counts)
                 pv = self._finalize(part, vp, csf, counts, init)
-            if self.profile:
-                s1 = sum(float(self.stage_times[k]) for k in inner_keys)
-                self.stage_times["finalize"] += \
-                    (time.perf_counter() - tf) - (s1 - s0)
+            if stages is not None:
+                stages.exit()
             p, v = pv
             if len(v):
                 paths_parts.append(p)
                 vals_parts.append(v)
 
-        tb = time.perf_counter() if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("output-build")
         if vals_parts:
             cols = [np.concatenate([p[d] for p in paths_parts], axis=0)
                     for d in range(len(red.out_ranks))]
@@ -730,10 +787,11 @@ class VectorBackend(ExecutorBackend):
             name, red.out_ranks, cols, vals,
             {r: None for r in red.out_ranks}, 0, set(red.upper_ranks),
             leaf_unique=True)
-        if self.profile:
-            self.stage_times["output-build"] += time.perf_counter() - tb
+        if stages is not None:
+            stages.exit()
 
-        self._emit(instr, name, counts)
+        with maybe_span("model:intake", "model"):
+            self._emit(instr, name, counts)
         stats = {"leaf_points": int(counts.get(("leaf",), 0)),
                  "muls": int(counts.get(("compute", "mul"), 0)),
                  "out_nnz": int(len(vals))}
@@ -841,10 +899,12 @@ class VectorBackend(ExecutorBackend):
                 # no explicit leader among the pair: lead with the
                 # smaller fiber (the dynamic choice real units make)
                 lead_is_left = ls.counts <= rs.counts
-        tk = time.perf_counter() if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("pair-merge")
         idx = kops.intersect_keys(lkeys, rkeys)
-        if self.profile:
-            self.stage_times["pair-merge"] += time.perf_counter() - tk
+        if stages is not None:
+            stages.exit()
         hit = idx >= 0
         sel = np.flatnonzero(hit)
         item_of = ls.item_of[sel]
@@ -872,10 +932,12 @@ class VectorBackend(ExecutorBackend):
     def _union(self, children, n_items: int, item_mult_of, ensure_keys):
         kops = self.kernels
         streams = [c.stream for c in children]
-        tk = time.perf_counter() if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("pair-merge")
         u, pos_list = kops.union_k_keys([ensure_keys(s) for s in streams])
-        if self.profile:
-            self.stage_times["pair-merge"] += time.perf_counter() - tk
+        if stages is not None:
+            stages.exit()
         item_of = u // max(item_mult_of(), 1)
         cnts = np.bincount(item_of, minlength=n_items).astype(np.int64)
         offs = np.zeros(n_items + 1, dtype=np.int64)
@@ -900,9 +962,9 @@ class VectorBackend(ExecutorBackend):
     # ------------------------------------------------------------------ #
     def _level(self, li: int, vp: VectorPlan, csf, fr: _Frontier,
                counts: Counter) -> _Frontier:
-        tm = time.perf_counter() if self.profile else 0.0
-        s0 = (float(self.stage_times["pair-merge"])
-              + float(self.stage_times["lookup"])) if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("materialize")
         lvl = vp.levels[li]
         rank = lvl.rank
         out_here = lvl.out_depth is not None
@@ -954,11 +1016,8 @@ class VectorBackend(ExecutorBackend):
         # stream conservation: a level cannot drain more frontier items
         # than its streams yielded (filters only ever shrink)
         check_conservation(n, nf.n, f"level:{vp.name}:{rank}")
-        if self.profile:
-            s1 = float(self.stage_times["pair-merge"]) \
-                + float(self.stage_times["lookup"])
-            self.stage_times["materialize"] += \
-                (time.perf_counter() - tm) - (s1 - s0)
+        if stages is not None:
+            stages.exit()
         return nf
 
     # ------------------------------------------------------------------ #
@@ -1023,10 +1082,12 @@ class VectorBackend(ExecutorBackend):
             pos = np.where(found, safe, -1)
             n_touch = int(found.sum())
         else:
-            tk = time.perf_counter() if self.profile else 0.0
+            stages = self._stages
+            if stages is not None:
+                stages.enter("lookup")
             idx = kops.lookup_keys(hay, probe_keys)
-            if self.profile:
-                self.stage_times["lookup"] += time.perf_counter() - tk
+            if stages is not None:
+                stages.exit()
             pos = np.where(pvalid, idx, -1)
             if neg is not None:
                 # the clamped stand-in probe may have matched; a negative
@@ -1191,10 +1252,12 @@ class VectorBackend(ExecutorBackend):
         # interpreter's sequential semiring.add, bit for bit; arith
         # rides one bincount pass, min-plus ufunc.reduceat, see
         # kernels.ops.segmented_reduce)
-        tr = time.perf_counter() if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("reduce")
         sums = kops.segmented_reduce(vals, starts, sr, group_ids=gids)
-        if self.profile:
-            self.stage_times["reduce"] += time.perf_counter() - tr
+        if stages is not None:
+            stages.exit()
         head = order[starts]             # pre-sort row of each group head
         out_rank = red.out_ranks[-1]
         # accounting: the first contribution of a group inserts (w);
@@ -1376,12 +1439,14 @@ class VectorBackend(ExecutorBackend):
         nzv = ws.buf("fm3", total, np.bool_)
         np.not_equal(vals, 0.0, out=nzv)
         all_nz = bool(nzv.all())
-        tr = time.perf_counter() if self.profile else 0.0
+        stages = self._stages
+        if stages is not None:
+            stages.enter("reduce")
         sums = np.bincount(key, weights=vals, minlength=size)
         exists = np.zeros(size, dtype=bool)
         exists[key if all_nz else key[nzv]] = True
-        if self.profile:
-            self.stage_times["reduce"] += time.perf_counter() - tr
+        if stages is not None:
+            stages.exit()
         idx = np.flatnonzero(exists)
         n_groups = len(idx)
         n_contrib = total if all_nz else int(np.count_nonzero(nzv))

@@ -217,22 +217,81 @@ def _init_device() -> None:
     """First use of a device backend: on a TPU, keep every compile
     (kernels compile in about a second, under JAX's default threshold)
     in the persistent cache.  JAX reads ``$JAX_COMPILATION_CACHE_DIR``
-    itself; CPU runs configure nothing and write nothing."""
+    itself; CPU runs configure nothing and write nothing.
+
+    The cache keys a Pallas kernel by its Mosaic payload, which holds
+    the kernel's source locations.  With full tracebacks those name
+    every caller up the stack, so one program reached along another
+    call path (a traced run's seam dispatch, another seam) missed the
+    cache and compiled again; locations keep the kernel's own frame
+    only."""
     import jax
     if jax.default_backend() != "tpu":
         return
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
 
 def _device(seam: str, fn, *args, **kwargs) -> np.ndarray:
     """Launch one seam program and wait for it before its result
-    leaves the device."""
+    leaves the device.
+
+    Under a tracer the launch, the wait and the read-back are one
+    ``device:<seam>`` span whose args name the padded ``bucket`` (the
+    shapes of the array arguments) and whether JAX built a program
+    inside it (``compiled``: a backend-compile event, which fires for a
+    compile and for a read of the persistent compile cache alike;
+    ``cache_hit``: the persistent cache served it)."""
     import jax
-    out = jax.block_until_ready(fn(*args, **kwargs))
-    _obs_metrics().counter("kernel.device_call/" + seam).inc()
-    return np.asarray(out)
+    tr = _obs_tracer()
+    if tr is None:
+        out = jax.block_until_ready(fn(*args, **kwargs))
+        _obs_metrics().counter("kernel.device_call/" + seam).inc()
+        return np.asarray(out)
+    builds = _compile_log()
+    compiles, hits = builds.compiles, builds.cache_hits
+    with tr.span("device:" + seam, cat="device") as sp:
+        out = jax.block_until_ready(fn(*args, **kwargs))
+        _obs_metrics().counter("kernel.device_call/" + seam).inc()
+        out = np.asarray(out)
+        sp.set("bucket", [list(a.shape) for a in args
+                          if isinstance(a, np.ndarray)])
+        sp.set("compiled", builds.compiles != compiles)
+        sp.set("cache_hit", builds.cache_hits != hits)
+    return out
+
+
+class _CompileLog:
+    """Programs JAX has built in this process, as ``jax.monitoring``
+    reports them (read by the ``device:`` spans)."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.compiles = 0
+        self.cache_hits = 0
+
+    def on_duration(self, event, duration, **_):
+        if event == self.COMPILE:
+            self.compiles += 1
+
+    def on_event(self, event, **_):
+        if event == self.CACHE_HIT:
+            self.cache_hits += 1
+
+
+@functools.cache
+def _compile_log() -> _CompileLog:
+    """The process's compile log, listening from the first traced
+    device launch on."""
+    import jax
+    log = _CompileLog()
+    jax.monitoring.register_event_duration_secs_listener(log.on_duration)
+    jax.monitoring.register_event_listener(log.on_event)
+    return log
 
 
 def _delegated(seam: str) -> None:
@@ -712,6 +771,16 @@ def _obs_metrics():
     return _METRICS_FN()
 
 
+def _n_keys(x) -> int:
+    """Elements of every array in ``x`` (an array, or a tuple or list
+    of them, nested); anything else counts nothing."""
+    if isinstance(x, np.ndarray):
+        return x.size
+    if isinstance(x, (tuple, list)):
+        return sum(_n_keys(v) for v in x)
+    return 0
+
+
 def _postcheck(seam: str, args, kwargs, out) -> None:
     """Cheap seam-contract postconditions (O(n) vectorized compares).
     A violation is *actionable* here -- the caller downgrades to the
@@ -907,12 +976,19 @@ class GuardedKernels:
         tr = _obs_tracer()
         if tr is None:
             # disabled path: identical to the pre-telemetry dispatch,
-            # no span / histogram objects touched
+            # no span or counter touched
             return self._dispatch(seam, args, kwargs, None)
         with tr.span("seam:" + seam, cat="seam",
                      args={"einsum": self.current_einsum}
                      if self.current_einsum else None) as sp:
-            return self._dispatch(seam, args, kwargs, sp)
+            out = self._dispatch(seam, args, kwargs, sp)
+            # the seam's work in keys, the same whatever serves it:
+            # every element it consumed plus every element it returned
+            keys = (_n_keys(args) + _n_keys(tuple(kwargs.values()))
+                    + _n_keys(out))
+            sp.set("keys", keys)
+            _obs_metrics().counter("kernel.seam_keys/" + seam).inc(keys)
+            return out
 
     def _dispatch(self, seam: str, args, kwargs, span):
         inj = _active_injector()
@@ -936,13 +1012,8 @@ class GuardedKernels:
                 try:
                     if inj is not None:
                         inj.before_seam(seam, bname)
-                    if span is not None:
-                        t0 = time.perf_counter()
                     out = getattr(backend, seam)(*args, **kwargs)
                     if span is not None:
-                        _obs_metrics().histogram(
-                            f"kernel.seam_seconds/{seam}/{bname}"
-                        ).observe(time.perf_counter() - t0)
                         span.set("backend", bname)
                         if attempts > 1:
                             span.set("attempts", attempts)

@@ -3,7 +3,10 @@
 Naming scheme (see DESIGN.md "Telemetry contract"): dotted component
 prefix, ``/``-separated label suffix --
 
-    kernel.seam_seconds/<seam>/<backend>     histogram (seam latency)
+    kernel.seam_keys/<seam>                  counter   (elements the
+                                                        seam calls consumed
+                                                        and returned;
+                                                        traced runs only)
     kernel.downgrade/<action>                counter   (retry/downgrade/
                                                         demote/unavailable)
     kernel.device_call/<seam>                counter   (programs launched
@@ -12,7 +15,6 @@ prefix, ``/``-separated label suffix --
                                                         calls handed to
                                                         numpy)
     guards.violation/<check>                 counter
-    vector.stage_seconds/<stage>             counter   (float seconds)
     dse.point/<status>                       counter   (ok/restored/...)
     dse.point_attempts                       counter
     dse.plan_cache/{hit,miss}                counter
@@ -23,15 +25,14 @@ prefix, ``/``-separated label suffix --
     dse.service/batch_size                   histogram (requests per
                                                         micro-batch)
 
-Counters accept float increments (stage seconds accumulate into a
-counter rather than a histogram: the per-stage distribution is already
-on the trace as spans).  Histograms use fixed bucket upper bounds so
-merging snapshots never re-bins.
+Counters accept float increments.  Histograms use fixed bucket upper
+bounds so merging snapshots never re-bins.  Durations are not kept
+here: each one is a span on the trace.
 
 The registry is cheap but not free; rare-event sites (downgrades,
-guard violations, sweep points) update it unconditionally, while
-per-seam latency observation only happens when a tracer is active --
-that keeps the disabled hot path allocation-free.
+guard violations, sweep points, device launches) update it
+unconditionally, while per-seam key counts are only taken when a
+tracer is active -- that keeps the disabled hot path allocation-free.
 """
 from __future__ import annotations
 

@@ -1,35 +1,49 @@
 """Hierarchical spans with a process-wide no-op default.
 
-The execution layer is instrumented at four nesting levels::
+The execution layer is instrumented at these nesting levels::
 
-    cascade:<spec>                  CascadeSimulator.run
+    cascade:<spec>                  CascadeSimulator.run (one simulation)
+      gen:transform                 partition / flatten / swizzle of the
+                                    inputs, merge detection
+      gen:restore                   exec-form output -> declared form
+      model:intake                  the performance model taking events
+      model:evaluate                the performance model's report
       einsum:<output>               one mapped Einsum on a backend
+        vec:lower                   plan lowering (vector engine)
+        vec:to_csf / vec:to_ftensor FTensor <-> CSF conversion
         stage:<name>                vector-pipeline stage (materialize,
                                     pair-merge, lookup, finalize,
                                     reduce, output-build)
           seam:<name>               one guarded kernel-dispatch call
+            device:<name>           one program launched on the JAX
+                                    device, waited for and read back
 
 Tracing is **off by default**: ``active_tracer()`` returns ``None``
 and every instrumentation site is a single cached-global read plus a
 ``None`` check (the same pattern the fault injector and guard knob
-use in ``kernels/backends.py``), so the hot path stays at the
-committed ``vector_rate`` when disabled.  ``maybe_span`` returns the
-shared :data:`NULL_SPAN` singleton in that case -- no allocation on
-the disabled path (asserted by ``tests/test_obs.py`` with
-``tracemalloc``).
+use in ``kernels/backends.py``), so the hot path is untouched when
+disabled.  ``maybe_span`` returns the shared :data:`NULL_SPAN`
+singleton in that case -- no allocation on the disabled path
+(asserted by ``tests/test_obs.py`` with ``tracemalloc``).
 
 A :class:`Tracer` collects finished spans as Chrome-trace-event
 dictionaries (``ph == "X"`` complete events, microsecond ``ts`` /
 ``dur`` relative to tracer start) plus instant events (``ph == "i"``)
-for downgrades, guard trips, and injected faults.  Nesting is tracked
-per-thread: each span records its parent span's name in
-``args["parent"]`` so tests (and humans) can assert the hierarchy
-without reconstructing it from time windows.  All mutation of the
-shared event list is lock-protected -- the DSE engine traces from
-worker threads.
+for downgrades, guard trips, and injected faults.  Every span also
+enters a ``jax.profiler.TraceAnnotation`` of its own name, so while a
+JAX profiler trace runs the program's spans sit on its ``/host:CPU``
+plane, on the same clock as the device's operations.
+
+Nesting is tracked per-thread: each span records its parent span's
+name in ``args["parent"]`` and the simulation it belongs to in
+``args["sim"]`` -- a number drawn at each ``cascade``-category span
+and inherited by the spans inside it.  All mutation of the shared
+event list is lock-protected -- the DSE engine traces from worker
+threads.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -93,10 +107,12 @@ class Span:
     """An open span; close via context-manager exit.
 
     ``set(key, value)`` attaches an arg visible in the exported trace
-    (usable both while open and from the ``with`` body).
+    (usable both while open and from the ``with`` body).  Once closed,
+    ``dur_us`` holds the duration the trace records.
     """
 
-    __slots__ = ("tracer", "name", "cat", "args", "_start_us", "parent")
+    __slots__ = ("tracer", "name", "cat", "args", "_start_us", "parent",
+                 "sim", "dur_us", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -106,6 +122,9 @@ class Span:
         self.args: Dict[str, Any] = dict(args) if args else {}
         self._start_us = 0.0
         self.parent: Optional[str] = None
+        self.sim: Optional[int] = None
+        self.dur_us = 0.0
+        self._annotation: Any = None
 
     def set(self, key: str, value: Any) -> None:
         self.args[key] = value
@@ -113,24 +132,47 @@ class Span:
     def __enter__(self) -> "Span":
         tr = self.tracer
         stack = tr._stack()
-        self.parent = stack[-1] if stack else None
-        stack.append(self.name)
+        if stack:
+            self.parent = stack[-1].name
+            self.sim = stack[-1].sim
+        if self.cat == "cascade":
+            self.sim = next(tr._sims)
+        stack.append(self)
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
         self._start_us = tr.now_us()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         tr = self.tracer
         end = tr.now_us()
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
         stack = tr._stack()
-        if stack and stack[-1] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         if self.parent is not None:
             self.args.setdefault("parent", self.parent)
-        tr.add_span(self.name, self.cat, self._start_us,
-                    end - self._start_us, self.args or None)
+        if self.sim is not None:
+            self.args.setdefault("sim", self.sim)
+        self.dur_us = end - self._start_us
+        tr.add_span(self.name, self.cat, self._start_us, self.dur_us,
+                    self.args or None)
         return False
+
+
+#: ``jax.profiler.TraceAnnotation``, imported on the first traced span
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
 
 
 class Tracer:
@@ -138,7 +180,8 @@ class Tracer:
 
     ``events`` is a list of finished trace-event dicts (``ph`` in
     ``{"X", "i"}``).  Timestamps are relative to tracer creation so a
-    trace always starts near ``ts == 0``.
+    trace always starts near ``ts == 0``.  Each span is also a
+    ``jax.profiler.TraceAnnotation`` of its name.
     """
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
@@ -147,6 +190,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._pid = os.getpid()
+        self._sims = itertools.count(1)
         self.events: List[Dict[str, Any]] = []
 
     # -- clock ---------------------------------------------------------
@@ -155,7 +199,7 @@ class Tracer:
         return (self._clock() - self._t0) * 1e6
 
     # -- per-thread nesting stack --------------------------------------
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = []
@@ -164,7 +208,7 @@ class Tracer:
 
     def current_span_name(self) -> Optional[str]:
         stack = self._stack()
-        return stack[-1] if stack else None
+        return stack[-1].name if stack else None
 
     # -- span / event emission -----------------------------------------
     def span(self, name: str, cat: str = "",
@@ -175,8 +219,7 @@ class Tracer:
     def add_span(self, name: str, cat: str, ts_us: float, dur_us: float,
                  args: Optional[Dict[str, Any]] = None,
                  tid: Optional[int] = None) -> None:
-        """Record a finished span directly (used both by :class:`Span`
-        and to synthesize stage spans from accumulated stage timers)."""
+        """Record a finished span (:class:`Span` calls this on exit)."""
         ev: Dict[str, Any] = {
             "name": name, "cat": cat or "span", "ph": "X",
             "ts": round(ts_us, 3), "dur": round(max(dur_us, 0.0), 3),

@@ -521,7 +521,8 @@ def test_jax_jit_reductions_stay_on_host_on_a_tpu(monkeypatch):
 
 def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
     """``$JAX_COMPILATION_CACHE_DIR`` wins; otherwise the cache is the
-    fixed in-checkout directory.  Only a TPU host configures either."""
+    fixed in-checkout directory.  Only a TPU host configures either, and
+    there it also keeps kernel locations to the kernel's own frame."""
     from pathlib import Path
     repo = Path(__file__).resolve().parent.parent
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
@@ -530,7 +531,8 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
     assert kbk.compile_cache_dir() == str(repo / ".jax_cache")
 
     keys = ("jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs")
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_include_full_tracebacks_in_locations")
     saved = {k: getattr(jax.config, k) for k in keys}
     try:
         kbk._init_device.__wrapped__()          # CPU: sets nothing
@@ -540,6 +542,9 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
         assert jax.config.jax_compilation_cache_dir == \
             str(repo / ".jax_cache")
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        # kernel locations keep their own frame: the cache key does not
+        # depend on the call path (tests/test_tpu_compile.py)
+        assert jax.config.jax_include_full_tracebacks_in_locations is False
         jax.config.update(keys[0], saved[keys[0]])
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         kbk._init_device.__wrapped__()          # JAX reads the variable

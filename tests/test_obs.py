@@ -3,12 +3,18 @@ registry, Perfetto export, and the instrumented seams.
 
 Contract under test (DESIGN.md "Telemetry contract"):
 
-  * spans nest ``cascade -> einsum -> stage / seam`` across
-    ``execute_batch``, with each span's parent recorded in
-    ``args["parent"]``;
+  * spans nest ``cascade -> gen / model / einsum -> vec / stage ->
+    seam -> device`` across ``execute_batch``, with each span's parent
+    recorded in ``args["parent"]`` and its simulation in
+    ``args["sim"]``;
+  * every span is also a host event of the JAX profiler's trace, on
+    the device's clock;
+  * stage spans are real intervals that never overlap and sum to
+    ``stage_seconds``;
   * the disabled path is free -- ``maybe_span`` returns the shared
-    ``NULL_SPAN`` and a guarded seam call allocates **nothing** in
-    ``obs/spans.py`` (asserted with ``tracemalloc``);
+    ``NULL_SPAN`` and neither a guarded seam call nor a whole
+    simulation allocates **anything** in ``obs/spans.py`` (asserted
+    with ``tracemalloc``);
   * the Chrome-trace export round-trips through ``json.loads`` with
     valid ``ph``/``ts``/``dur`` fields and Perfetto-required instant
     markers;
@@ -22,11 +28,13 @@ Contract under test (DESIGN.md "Telemetry contract"):
     hint passed through the tee verbatim.
 """
 import json
+import time
 
 import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # hypothesis, or seeded fallback
+import chip_smoke
 from repro.accelerators import gamma
 from repro.core.generator import CascadeSimulator
 from repro.core.trace import CollectingInstr, Instrumentation, TeeInstr
@@ -139,6 +147,36 @@ def test_disabled_seam_path_allocates_nothing_in_spans():
     assert sum(s.size for s in stats) == 0, stats
 
 
+def test_disabled_span_sites_allocate_nothing(rng, monkeypatch):
+    """Every span site of a whole simulation -- the generator's
+    ``gen:`` / ``model:`` spans, the engine's ``vec:`` spans, the
+    stage clock and the device launch -- stays on the disabled path
+    without a tracer: nothing is allocated in ``obs/spans.py``, no
+    stage clock is built and no compile log is consulted."""
+    import tracemalloc
+
+    import repro.core.vectorized as vec_mod
+    import repro.obs.spans as spans_mod
+
+    def refused(*a, **k):
+        raise AssertionError("built on the disabled path")
+
+    monkeypatch.setattr(vec_mod, "_StageClock", refused)
+    monkeypatch.setattr(kbk, "_compile_log", refused)
+    workload = chip_smoke.gamma_workload(n=48, nnz=300, seed=1)
+    chip_smoke.gamma_phase(workload, "jax-jit")       # warm caches
+    tracemalloc.start()
+    try:
+        chip_smoke.gamma_phase(workload, "jax-jit")
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    stats = snap.filter_traces(
+        [tracemalloc.Filter(True, spans_mod.__file__)]
+    ).statistics("filename")
+    assert sum(s.size for s in stats) == 0, stats
+
+
 # ---------------------------------------------------------------------- #
 # metrics registry
 # ---------------------------------------------------------------------- #
@@ -176,8 +214,9 @@ def test_metrics_registry_same_instrument_identity():
 # ---------------------------------------------------------------------- #
 def test_spans_nest_across_execute_batch(rng):
     """Gamma's two-Einsum cascade through the vector backend: one
-    cascade span, one einsum span per Einsum parented to it, seam and
-    stage spans parented to their einsum."""
+    cascade span, one einsum span per Einsum parented to it, stage
+    spans parented to their einsum and lying inside it, seam spans
+    inside a stage."""
     inputs, shapes = _spmm(rng)
     sim, _ = _vector_sim()
     with trace_session() as tr:
@@ -191,45 +230,92 @@ def test_spans_nest_across_execute_batch(rng):
     for e in einsums:
         assert e["args"]["parent"] == cname
         assert e["args"]["path"] == "vector"
-    # stage spans always belong to an einsum; seam spans may also fire
-    # at cascade level (CSF construction), never unparented here
-    for e in tr.spans("stage"):
-        assert e["args"]["parent"] in {"einsum:T", "einsum:Z"}, e
+    stages = tr.spans("stage")
+    assert stages
+    for e in stages:
+        assert e["args"]["parent"] == "einsum:" + e["args"]["einsum"], e
+        assert "synthetic" not in e["args"]
     seams = tr.spans("seam")
     assert seams, "guarded seam calls must produce spans"
-    parents = {e["args"]["parent"] for e in seams}
-    assert parents <= {cname, "einsum:T", "einsum:Z"}
-    assert parents & {"einsum:T", "einsum:Z"}, parents
-    stages = tr.spans("stage")
-    assert stages and all(e["args"]["synthetic"] for e in stages)
-    # every span inside its einsum's wall-clock window (synthetic stage
-    # spans are laid out inside it by construction)
+    assert {e["args"]["parent"] for e in seams} <= {
+        e["name"] for e in stages}
+    # every stage span is a real interval inside its einsum's window
     win = {e["name"]: (e["ts"], e["ts"] + e["dur"]) for e in einsums}
     for e in stages:
         lo, hi = win[e["args"]["parent"]]
-        assert e["ts"] >= lo - 1.0 and e["ts"] + e["dur"] <= hi + 1.0
+        assert e["ts"] >= lo and e["ts"] + e["dur"] <= hi + 1.0
+    # one simulation: every span carries the cascade's id
+    assert {e["args"]["sim"] for e in tr.spans()} == {
+        cascades[0]["args"]["sim"]}
 
 
 def test_seam_spans_carry_backend_and_histogram(rng):
+    """Each seam span names the backend that served it and the keys
+    the call consumed and returned; the keys add up on the
+    ``kernel.seam_keys/<seam>`` counters, and no seam-latency
+    histogram is kept (the span's duration is the latency)."""
     inputs, shapes = _spmm(rng)
     sim, _ = _vector_sim()
     with trace_session() as tr:
         sim.run(dict(inputs), shapes)
     seams = tr.spans("seam")
     assert all(e["args"]["backend"] == "numpy" for e in seams)
+    assert all(e["args"]["keys"] > 0 for e in seams)
     snap = metrics().snapshot()
-    hists = [k for k in snap["histograms"]
-             if k.startswith("kernel.seam_seconds/")]
-    assert hists, snap
-    assert all(k.endswith("/numpy") for k in hists)
-    total = sum(snap["histograms"][k]["count"] for k in hists)
-    assert total == len(seams)
+    assert not snap["histograms"], snap["histograms"]
+    keys = {k[len("kernel.seam_keys/"):]: v
+            for k, v in snap["counters"].items()
+            if k.startswith("kernel.seam_keys/")}
+    assert keys
+    for seam, total in keys.items():
+        assert total == sum(e["args"]["keys"] for e in seams
+                            if e["name"] == "seam:" + seam)
+
+
+def test_seam_keys_count_what_each_call_consumed_and_returned():
+    gk = kbk.GuardedKernels("numpy", sleep=lambda s: None)
+    a = np.array([1, 3, 5, 7, 9], dtype=np.int64)
+    b = np.array([3, 7, 11], dtype=np.int64)
+    probes = np.array([7, 2, 9, 9], dtype=np.int64)
+    with trace_session():
+        gk.intersect_keys(a, b)
+        gk.lookup_keys(a, probes)
+    c = metrics().snapshot()["counters"]
+    assert c["kernel.seam_keys/intersect_keys"] == 5 + 3 + 5
+    assert c["kernel.seam_keys/lookup_keys"] == 5 + 4 + 4
+    gk.intersect_keys(a, b)          # untraced: not counted
+    assert metrics().snapshot()["counters"][
+        "kernel.seam_keys/intersect_keys"] == 13
+
+
+def _seam_keys(phase, workload, backend):
+    metrics().reset()
+    with trace_session():
+        phase(workload, backend)
+    return {k: v for k, v in metrics().snapshot()["counters"].items()
+            if k.startswith("kernel.seam_keys/")}
+
+
+@pytest.mark.parametrize("phase", ["gamma", "bfs"])
+def test_seam_keys_identical_under_numpy_and_pallas(phase):
+    """The key counters measure the work of the seam, not of the kernel
+    that serves it: the same inputs count the same keys under the numpy
+    lowering and the interpreted Pallas kernel."""
+    if phase == "gamma":
+        workload = chip_smoke.gamma_workload(n=48, nnz=300, seed=2)
+        run = chip_smoke.gamma_phase
+    else:
+        workload = chip_smoke.bfs_workload(side=8, seed=2)
+        run = lambda w, kb: chip_smoke.bfs_phase(w, kb, max_iters=4)  # noqa
+    oracle = _seam_keys(run, workload, "numpy")
+    assert oracle.get("kernel.seam_keys/intersect_keys", 0) > 0
+    assert _seam_keys(run, workload, "pallas-interpret") == oracle
 
 
 def test_stage_seconds_on_simresult_and_report(rng):
     inputs, shapes = _spmm(rng)
     sim, vb = _vector_sim(model=True)
-    with trace_session():
+    with trace_session() as tr:
         res = sim.run(dict(inputs), shapes)
     assert set(res.stage_seconds) == {"T", "Z"}
     for per in res.stage_seconds.values():
@@ -243,9 +329,164 @@ def test_stage_seconds_on_simresult_and_report(rng):
     assert res.report.stage_seconds == pytest.approx(agg)
     # the backend's own counters hold the last-executed request (Z)
     assert vb.stage_seconds == pytest.approx(res.stage_seconds["Z"])
-    snap = metrics().snapshot()
-    assert any(k.startswith("vector.stage_seconds/")
-               for k in snap["counters"])
+    # the stage spans carry the same seconds; no counter repeats them
+    for einsum, per in res.stage_seconds.items():
+        spans = {}
+        for e in tr.spans("stage"):
+            if e["args"]["einsum"] == einsum:
+                stage = e["name"][len("stage:"):]
+                spans[stage] = spans.get(stage, 0.0) + e["dur"] / 1e6
+        assert spans == pytest.approx(per, rel=1e-2)
+    assert not any(k.startswith("vector.stage_seconds/")
+                   for k in metrics().snapshot()["counters"])
+
+
+@pytest.mark.parametrize("phase", ["gamma", "bfs"])
+def test_stage_spans_never_overlap_and_sum_to_stage_seconds(phase):
+    """Nested stages (finalize around reduce and whole levels,
+    materialize around pair-merge and lookup) are charged exclusively:
+    the stage spans of one Einsum never overlap, and their durations
+    sum to ``stage_seconds`` within 1%."""
+    if phase == "gamma":
+        workload = chip_smoke.gamma_workload(n=64, nnz=500, seed=4)
+        with trace_session() as tr:
+            res, _ = chip_smoke.gamma_phase(workload, "numpy")
+        runs = [(tr.spans(), res.stage_seconds)]
+    else:
+        # one iteration: the result's stage_seconds are its cascade's
+        workload = chip_smoke.bfs_workload(side=10, seed=4)
+        with trace_session() as tr:
+            res, _ = chip_smoke.bfs_phase(workload, "numpy", max_iters=1)
+        runs = [(tr.spans(), res.stage_seconds)]
+    for spans, stage_seconds in runs:
+        assert stage_seconds
+        for einsum, per in stage_seconds.items():
+            mine = sorted((e for e in spans if e["cat"] == "stage"
+                           and e["args"]["einsum"] == einsum),
+                          key=lambda e: e["ts"])
+            for x, y in zip(mine, mine[1:]):
+                assert x["ts"] + x["dur"] <= y["ts"] + 1e-3, (x, y)
+            total = sum(e["dur"] for e in mine) / 1e6
+            assert total == pytest.approx(sum(per.values()), rel=1e-2)
+
+
+#: each new span and the parent it must have
+NEW_SPANS = {
+    "gen:transform": "cascade:", "gen:restore": "cascade:",
+    "model:evaluate": "cascade:", "vec:lower": "einsum:",
+    "vec:to_csf": "einsum:", "vec:to_ftensor": "einsum:",
+    "device:intersect_keys": "seam:intersect_keys",
+}
+
+
+@pytest.mark.parametrize("phase", ["gamma", "bfs"])
+def test_every_new_span_appears_with_its_parent(phase):
+    """One Gamma ``simulate()`` and one Ours-VCP ``run_iterative`` on
+    the interpreted Pallas kernels carry every new span, each under
+    the parent its layer puts it in; ``model:intake`` appears under
+    the cascade (merger events) or an einsum (the engine's events), and
+    ``device:`` spans name their padded bucket and whether a program
+    was built."""
+    if phase == "gamma":
+        workload = chip_smoke.gamma_workload(n=48, nnz=300, seed=5)
+        with trace_session() as tr:
+            chip_smoke.gamma_phase(workload, "pallas-interpret")
+    else:
+        workload = chip_smoke.bfs_workload(side=8, seed=5)
+        with trace_session() as tr:
+            chip_smoke.bfs_phase(workload, "pallas-interpret",
+                                 max_iters=3)
+    spans = tr.spans()
+    for name, parent in NEW_SPANS.items():
+        mine = [e for e in spans if e["name"] == name]
+        assert mine, name
+        assert all(e["args"]["parent"].startswith(parent) for e in mine), \
+            (name, {e["args"]["parent"] for e in mine})
+    intake = {e["args"]["parent"].split(":")[0] for e in spans
+              if e["name"] == "model:intake"}
+    assert "einsum" in intake
+    if phase == "gamma":       # Gamma merges its intermediate T
+        assert "cascade" in intake
+    for e in spans:
+        if e["name"].startswith("device:"):
+            assert e["args"]["bucket"] and all(
+                isinstance(n, int) for shape in e["args"]["bucket"]
+                for n in shape)
+            assert isinstance(e["args"]["compiled"], bool)
+    # the generator and model spans never overlap one another
+    top = sorted((e for e in spans
+                  if e["name"].split(":")[0] in ("gen", "model")
+                  and e["args"]["parent"].startswith("cascade:")),
+                 key=lambda e: e["ts"])
+    for x, y in zip(top, top[1:]):
+        assert x["ts"] + x["dur"] <= y["ts"] + 1e-3, (x, y)
+
+
+@pytest.mark.parametrize("phase", ["gamma", "bfs"])
+def test_statistics_bit_identical_with_and_without_tracer(phase):
+    """Tracing observes; it never changes a simulated statistic."""
+    if phase == "gamma":
+        workload = chip_smoke.gamma_workload(n=64, nnz=500, seed=6)
+        run = chip_smoke.gamma_phase
+    else:
+        workload = chip_smoke.bfs_workload(side=10, seed=6)
+        run = lambda w, kb: chip_smoke.bfs_phase(w, kb, max_iters=5)  # noqa
+    plain = chip_smoke.fingerprint(*run(workload, "numpy"))
+    with trace_session():
+        traced = chip_smoke.fingerprint(*run(workload, "numpy"))
+    assert traced == plain
+
+
+def test_span_is_a_profiler_host_event_inside_the_job(tmp_path):
+    """With a tracer installed, a span is also a host event of the
+    same name in the JAX profiler's trace, on its clock, nested inside
+    the benchmark's ``job`` annotation."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace_session() as tr:
+            with jax.profiler.TraceAnnotation("job 0"):
+                with tr.span("cascade:probe", "cascade"):
+                    with tr.span("gen:transform", "gen"):
+                        time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    data = ProfileData.from_file(str(files[-1]))
+    host = {}
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name] = (ev.start_ns,
+                                     ev.start_ns + ev.duration_ns)
+    job, casc, gen_ = (host["job 0"], host["cascade:probe"],
+                       host["gen:transform"])
+    assert job[0] <= casc[0] <= gen_[0]
+    assert gen_[1] <= casc[1] <= job[1]
+    assert gen_[1] - gen_[0] >= 2e6
+    sims = {e["args"]["sim"] for e in tr.spans()}
+    assert len(sims) == 1
+
+
+def test_each_cascade_span_starts_a_simulation():
+    with trace_session() as tr:
+        for _ in range(2):
+            with tr.span("cascade:x", "cascade"):
+                with tr.span("einsum:Z", "einsum"):
+                    pass
+        with tr.span("loose", "t"):
+            pass
+    by = {}
+    for e in tr.spans():
+        by.setdefault(e["name"], []).append(e.get("args", {}).get("sim"))
+    assert by["cascade:x"] == by["einsum:Z"] and len(set(by["cascade:x"])) == 2
+    assert by["loose"] == [None]
 
 
 def test_stage_seconds_absent_when_disabled(rng):

@@ -2,8 +2,11 @@
 described but not attached: the Pallas rank kernel (through its two
 entry points) at the largest padded shapes the chip smoke run meets,
 and the jax-jit XLA seam programs under x64 at about 1M keys.  What
-Mosaic or XLA would refuse on the chip fails here, at no chip time."""
+Mosaic or XLA would refuse on the chip fails here, at no chip time.
+Also: the kernel's payload, part of the persistent compile cache's
+key, does not depend on the call path that reaches it."""
 import os
+import re
 
 import pytest
 
@@ -86,3 +89,39 @@ def test_jax_jit_seam_programs_compile_for_v5e(one_chip):
         for name, lowered in progs.items():
             compiled = lowered.compile()
             assert compiled.memory_analysis() is not None, name
+
+
+def _mosaic_body(spec):
+    """The Mosaic payload of the rank kernel lowered afresh for ``spec``
+    (its bytes are part of the persistent compile cache's key)."""
+    jax.clear_caches()
+    text = isect.intersect_sorted.trace(spec, spec).lower().as_text(
+        debug_info=True)
+    return re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)', text).group(1)
+
+
+def _lowered_here(spec):
+    return _mosaic_body(spec)
+
+
+def _lowered_there(spec):
+    return _mosaic_body(spec)
+
+
+def test_kernel_cache_key_does_not_depend_on_the_call_path(one_chip):
+    """With full tracebacks in MLIR locations, the kernel's payload
+    names the line of every caller, so one program reached from two
+    call paths has two cache keys (a traced run missed the untraced
+    runs' cache this way).  Under the setting a TPU backend applies
+    (``kernels.backends._init_device``) the paths agree."""
+    spec = _spec((2048,), jnp.int32, one_chip)
+    key = "jax_include_full_tracebacks_in_locations"
+    prev = getattr(jax.config, key)
+    try:
+        jax.config.update(key, True)
+        assert _lowered_here(spec) != _lowered_there(spec)
+        jax.config.update(key, False)
+        assert _lowered_here(spec) == _lowered_there(spec)
+    finally:
+        jax.config.update(key, prev)
+        jax.clear_caches()
