@@ -18,14 +18,21 @@ and the Sparse Abstract Machine use for scaling this class of model:
                        with the innermost coords.
 
 Conversion ``FTensor <-> CSF`` is lossless (same rank names, shapes,
-coordinate order, upper-rank markers), and the TeAAL Section 3.2
-content-preserving transformations -- rank swizzling, uniform-shape /
-uniform-occupancy partitioning, rank flattening -- are reimplemented
-here as vectorized array ops with semantics identical to the Fiber
-implementations (asserted by tests/test_csf.py).
+coordinate order, upper-rank markers) and moves whole fibers, not
+elements: one level of the array layout is the concatenation of that
+rank's fibers in parent order.  Before it builds Fibers from arrays,
+the ``sorted-coords`` guard (``guards.check_sorted_segments``) checks
+that coordinates strictly increase within each segment.
+
+The TeAAL Section 3.2 content-preserving transformations -- rank
+swizzling, uniform-shape / uniform-occupancy partitioning, rank
+flattening -- are reimplemented here as vectorized array ops with
+semantics identical to the Fiber implementations (asserted by
+tests/test_csf.py).
 """
 from __future__ import annotations
 
+from itertools import chain
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -107,62 +114,57 @@ class CSF:
     # ------------------------------------------------------------------ #
     @staticmethod
     def from_ftensor(ft: FTensor) -> "CSF":
+        """Walk the tree a level at a time: one level of a CSF in
+        depth-first order is the concatenation of that level's fibers
+        taken in parent order, so each fiber moves with one extend."""
         L = len(ft.ranks)
-        coords: List[List[Tuple[int, ...]]] = [[] for _ in range(L)]
-        segments: List[List[int]] = [[0] for _ in range(L)]
-        values: List[Any] = []
-
-        def rec(fiber: Fiber, depth: int) -> None:
-            for c, p in fiber:
-                coords[depth].append(c if isinstance(c, tuple) else (c,))
-                if depth == L - 1:
-                    values.append(p)
-                else:
-                    assert isinstance(p, Fiber), \
-                        f"{ft.name}: non-fiber payload above leaf rank"
-                    rec(p, depth + 1)
-                    segments[depth + 1].append(len(coords[depth + 1]))
-
-        if L:
-            rec(ft.root, 0)
-        widths = [max((len(t) for t in coords[d]), default=1)
-                  for d in range(L)]
-        carr = [np.asarray(coords[d], dtype=np.int64).reshape(
-                    len(coords[d]), widths[d]) for d in range(L)]
-        segs: List[Optional[np.ndarray]] = [None] + [
-            np.asarray(segments[d], dtype=np.int64) for d in range(1, L)]
-        vals = np.asarray(values, dtype=np.float64) if values else \
-            np.zeros(0, dtype=np.float64)
+        carr: List[np.ndarray] = []
+        segs: List[Optional[np.ndarray]] = [None]
+        vals = np.zeros(0, dtype=np.float64)
+        fibers: List[Fiber] = [ft.root]
+        for d in range(L):
+            level = list(chain.from_iterable(f.coords for f in fibers))
+            if d:
+                segs.append(np.concatenate(
+                    ([0], np.cumsum([len(f) for f in fibers],
+                                    dtype=np.int64))))
+            # flattened ranks hold tuple coordinates, all of one length
+            width = len(level[0]) if level and isinstance(level[0], tuple) \
+                else 1
+            carr.append(np.asarray(level, dtype=np.int64).reshape(
+                len(level), width))
+            payloads = list(chain.from_iterable(f.payloads for f in fibers))
+            if d == L - 1:
+                vals = np.asarray(payloads, dtype=np.float64)
+            else:
+                assert all(isinstance(p, Fiber) for p in payloads), \
+                    f"{ft.name}: non-fiber payload above leaf rank"
+                fibers = payloads
         return CSF(ft.name, ft.ranks, carr, segs, vals,
                    dict(ft.rank_shapes), ft.default, set(ft.upper_ranks))
 
     def to_ftensor(self) -> FTensor:
+        """Build the tree bottom-up: every element of rank d-1 gets one
+        Fiber whose coords and payloads are slices of rank d's lists."""
         L = self.ndim
         out = FTensor(self.name, self.ranks, Fiber(),
                       dict(self.rank_shapes), self.default,
                       set(self.upper_ranks))
         if L == 0 or self.nnz == 0:
             return out
-        clists = [c.tolist() for c in self.coords]
-        widths = [self.level_width(d) for d in range(L)]
-        vals = self.values.tolist()
-
-        def coord_of(d: int, i: int):
-            row = clists[d][i]
-            return tuple(row) if widths[d] > 1 else row[0]
-
-        def build(d: int, lo: int, hi: int) -> Fiber:
-            fiber = Fiber()
-            for i in range(lo, hi):
-                if d == L - 1:
-                    fiber.append(coord_of(d, i), vals[i])
-                else:
-                    seg = self.segments[d + 1]
-                    fiber.append(coord_of(d, i),
-                                 build(d + 1, int(seg[i]), int(seg[i + 1])))
-            return fiber
-
-        out.root = build(0, 0, len(self.coords[0]))
+        payloads: List[Any] = self.values.tolist()
+        for d in range(L - 1, -1, -1):
+            c, seg = self.coords[d], self.segments[d]
+            guards.check_sorted_segments(
+                c, seg, f"csf:{self.name}:{self.ranks[d]}")
+            coords = c[:, 0].tolist() if c.shape[1] == 1 \
+                else list(map(tuple, c.tolist()))
+            if d == 0:
+                out.root = Fiber(coords, payloads)
+            else:
+                b = seg.tolist()
+                payloads = [Fiber(coords[lo:hi], payloads[lo:hi])
+                            for lo, hi in zip(b, b[1:])]
         return out
 
     @staticmethod

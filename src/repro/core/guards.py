@@ -13,7 +13,8 @@ single process-wide knob:
 
 The checks are deliberately O(n) single-pass or O(1): a NaN/inf scan
 over leaf values (arithmetic semirings only -- min-plus legitimately
-folds infinities), a monotone-segments check on CSF builds, and stream
+folds infinities), a monotone-segments check on CSF builds, a
+sorted-coordinates check on CSF -> fibertree conversion, and stream
 conservation ((yielded, drained) accounting) on frontier levels.  The
 guard budget is <= 3% of hot-path wall time at the default level
 (asserted by ``BENCH_backend.json`` regressions).
@@ -28,7 +29,7 @@ from __future__ import annotations
 
 import os
 import warnings
-from typing import Set, Tuple
+from typing import Optional, Set, Tuple
 
 import numpy as np
 
@@ -117,6 +118,28 @@ def check_monotone_segments(seg: np.ndarray, site: str) -> None:
                             and bool((np.diff(seg) < 0).any())):
         violation("monotone-segments", site,
                   "segment offsets decrease or do not start at 0")
+
+
+def check_sorted_segments(coords: np.ndarray, seg: Optional[np.ndarray],
+                          site: str) -> None:
+    """Within each CSF segment, coordinates must strictly increase
+    (rows of a flattened rank compare lexicographically).  ``seg`` is
+    None for rank 0, whose one segment is the whole level."""
+    n = len(coords)
+    if level() == "off" or n < 2:
+        return
+    step = np.diff(coords.astype(np.int64), axis=0)
+    # the first column that moves decides; a repeated row moves none
+    lead = step[:, 0] if step.shape[1] == 1 else \
+        step[np.arange(n - 1), (step != 0).argmax(axis=1)]
+    up = lead > 0
+    if seg is not None:          # a segment's first element has no rival
+        starts = np.asarray(seg)
+        up[starts[(starts > 0) & (starts < n)] - 1] = True
+    if not bool(up.all()):
+        violation("sorted-coords", site,
+                  f"{int((~up).sum())} of {n} coordinates do not rise "
+                  f"within their segment")
 
 
 def check_conservation(yielded: int, drained: int, site: str) -> None:

@@ -1,12 +1,15 @@
 """Columnar CSF representation: lossless FTensor round-trips and
 vectorized Section-3.2 transforms equivalent to the Fiber reference
 implementations."""
+from typing import Any, List
+
 import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # hypothesis, or seeded fallback
 from repro.core.csf import CSF
-from repro.core.fibertree import FTensor
+from repro.core.fibertree import Fiber, FTensor
+from repro.core.guards import GuardViolation
 
 
 def rand_dense(seed, shape, density=0.3):
@@ -57,6 +60,200 @@ def test_empty_and_1d():
     cs = CSF.from_ftensor(v)
     assert cs.nnz == 2
     assert_same_tree(v, cs)
+
+
+# ---------------------------------------------------------------------- #
+# per-fiber conversion vs the element-at-a-time reference
+# ---------------------------------------------------------------------- #
+def ref_from_ftensor(ft: FTensor) -> CSF:
+    """FTensor -> CSF one element at a time (the reference)."""
+    L = len(ft.ranks)
+    coords: List[List[tuple]] = [[] for _ in range(L)]
+    segments: List[List[int]] = [[0] for _ in range(L)]
+    values: List[Any] = []
+
+    def rec(fiber: Fiber, depth: int) -> None:
+        for c, p in fiber:
+            coords[depth].append(c if isinstance(c, tuple) else (c,))
+            if depth == L - 1:
+                values.append(p)
+            else:
+                assert isinstance(p, Fiber)
+                rec(p, depth + 1)
+                segments[depth + 1].append(len(coords[depth + 1]))
+
+    if L:
+        rec(ft.root, 0)
+    widths = [max((len(t) for t in coords[d]), default=1) for d in range(L)]
+    carr = [np.asarray(coords[d], dtype=np.int64).reshape(
+                len(coords[d]), widths[d]) for d in range(L)]
+    segs = [None] + [np.asarray(segments[d], dtype=np.int64)
+                     for d in range(1, L)]
+    vals = np.asarray(values, dtype=np.float64) if values else \
+        np.zeros(0, dtype=np.float64)
+    return CSF(ft.name, ft.ranks, carr, segs, vals,
+               dict(ft.rank_shapes), ft.default, set(ft.upper_ranks))
+
+
+def ref_to_ftensor(cs: CSF) -> FTensor:
+    """CSF -> FTensor one ``Fiber.append`` per element (the reference)."""
+    L = cs.ndim
+    out = FTensor(cs.name, cs.ranks, Fiber(), dict(cs.rank_shapes),
+                  cs.default, set(cs.upper_ranks))
+    if L == 0 or cs.nnz == 0:
+        return out
+    clists = [c.tolist() for c in cs.coords]
+    vals = cs.values.tolist()
+
+    def coord_of(d: int, i: int):
+        row = clists[d][i]
+        return tuple(row) if cs.level_width(d) > 1 else row[0]
+
+    def build(d: int, lo: int, hi: int) -> Fiber:
+        fiber = Fiber()
+        for i in range(lo, hi):
+            if d == L - 1:
+                fiber.append(coord_of(d, i), vals[i])
+            else:
+                seg = cs.segments[d + 1]
+                fiber.append(coord_of(d, i),
+                             build(d + 1, int(seg[i]), int(seg[i + 1])))
+        return fiber
+
+    out.root = build(0, 0, len(cs.coords[0]))
+    return out
+
+
+def kron_graph(seed: int, scale: int, edgefactor: int = 16) -> FTensor:
+    """G[S, D] of an undirected, deduplicated Graph500 Kronecker graph
+    without self loops (the BFS cells' input, at a small scale)."""
+    r = np.random.default_rng(seed)
+    a, b, c = 0.57, 0.19, 0.19
+    m = edgefactor << scale
+    i = np.zeros(m, np.int64)
+    j = np.zeros(m, np.int64)
+    for bit in range(scale):
+        ii = r.random(m) > a + b
+        jj = r.random(m) > np.where(ii, c / (1 - a - b), a / (a + b))
+        i |= ii.astype(np.int64) << bit
+        j |= jj.astype(np.int64) << bit
+    keep = i != j
+    src = np.concatenate([i[keep], j[keep]])
+    dst = np.concatenate([j[keep], i[keep]])
+    v = 1 << scale
+    pts = np.unique(np.stack([src, dst], axis=1), axis=0)
+    cs = CSF.from_coo("G", ["S", "D"], pts, np.ones(len(pts)),
+                      {"S": v, "D": v})
+    return ref_to_ftensor(cs)
+
+
+def _ft(name, ranks, seed, shape, density=0.3):
+    return FTensor.from_dense(name, ranks, rand_dense(seed, shape, density))
+
+
+CONVERSION_CASES = {
+    "1-rank": lambda: _ft("V", ["K"], 21, (17,)),
+    "2-rank": lambda: _ft("A", ["M", "K"], 22, (9, 12)),
+    "3-rank": lambda: _ft("T", ["M", "K", "N"], 23, (5, 7, 6)),
+    "flattened-outer": lambda: _ft(
+        "T", ["M", "K", "N"], 24, (4, 5, 3)).flatten_ranks("M", "K"),
+    "flattened-leaf": lambda: _ft(
+        "T", ["M", "K", "N"], 24, (4, 5, 3)).flatten_ranks("K", "N"),
+    "partitioned-shape": lambda: _ft(
+        "A", ["M", "K"], 25, (8, 11)).partition_uniform_shape("K", 3),
+    "partitioned-occupancy": lambda: _ft(
+        "A", ["M", "K"], 26, (8, 11), 0.5).partition_uniform_occupancy(
+            "M", 4),
+    "empty": lambda: FTensor.from_dense("E", ["M", "K"], np.zeros((4, 4))),
+    "empty-inner-fibers": lambda: FTensor(
+        "I", ["M", "K"],
+        Fiber([0, 2, 5, 7], [Fiber([1, 3], [1.0, 2.5]), Fiber(),
+                             Fiber([0], [4.0]), Fiber()]),
+        {"M": 8, "K": 4}),
+    "only-empty-inner-fibers": lambda: FTensor(
+        "O", ["M", "K", "N"],
+        Fiber([1, 3], [Fiber([0], [Fiber()]), Fiber()]),
+        {"M": 4, "K": 2, "N": 2}),
+    "bfs-kron-graph": lambda: kron_graph(1, 9),
+}
+
+
+def _leaf_types(fiber: Fiber, depth: int, out: List[list]) -> None:
+    """Types of every coordinate (and leaf value) level by level,
+    tuple coordinates unpacked."""
+    for c, p in fiber:
+        out[depth].append(type(c))
+        if isinstance(c, tuple):
+            out[depth].extend(type(x) for x in c)
+        if isinstance(p, Fiber):
+            _leaf_types(p, depth + 1, out)
+        else:
+            out[depth].append(type(p))
+
+
+def assert_same_ftensor(a: FTensor, b: FTensor):
+    assert (a.name, a.ranks, a.rank_shapes, a.default, a.upper_ranks) == \
+        (b.name, b.ranks, b.rank_shapes, b.default, b.upper_ranks)
+    assert a.root == b.root
+    ta, tb = ([[] for _ in a.ranks] for _ in range(2))
+    _leaf_types(a.root, 0, ta)
+    _leaf_types(b.root, 0, tb)
+    assert ta == tb
+    assert set().union(*map(set, ta)) <= {int, tuple, float}
+
+
+def assert_same_csf(a: CSF, b: CSF):
+    assert (a.name, a.ranks, a.rank_shapes, a.default, a.upper_ranks) == \
+        (b.name, b.ranks, b.rank_shapes, b.default, b.upper_ranks)
+    for x, y in zip(a.coords + a.segments[1:] + [a.values],
+                    b.coords + b.segments[1:] + [b.values]):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert np.array_equal(x, y)
+    assert a.segments[0] is None and b.segments[0] is None
+
+
+@pytest.mark.parametrize("case", list(CONVERSION_CASES))
+def test_conversion_matches_elementwise_reference(case):
+    ft = CONVERSION_CASES[case]()
+    cs, ref = CSF.from_ftensor(ft), ref_from_ftensor(ft)
+    assert_same_csf(cs, ref)
+    back = cs.to_ftensor()
+    assert_same_ftensor(back, ref_to_ftensor(ref))
+    if ft.nnz:                   # a tree without leaves comes back empty
+        assert_same_ftensor(back, ft)
+
+
+def test_non_fiber_payload_above_leaf_raises():
+    ft = FTensor("X", ["M", "K"], Fiber([0, 1], [Fiber([2], [1.0]), 3.0]))
+    with pytest.raises(AssertionError, match="non-fiber payload"):
+        CSF.from_ftensor(ft)
+
+
+@pytest.mark.parametrize("coords,segments,bad", [
+    # leaf coordinates restart in each segment: sorted
+    ([[0, 1], [3, 5, 1]], [None, [0, 2, 3]], False),
+    # an empty segment between two restarts: sorted
+    ([[0, 1, 2], [3, 5, 1]], [None, [0, 2, 2, 3]], False),
+    # out of order inside the first segment
+    ([[0, 1], [5, 3, 1]], [None, [0, 2, 3]], True),
+    # a repeated coordinate inside a segment
+    ([[0, 1], [3, 3, 1]], [None, [0, 2, 3]], True),
+    # out of order at the root
+    ([[1, 0], [3, 5, 1]], [None, [0, 2, 3]], True),
+    # flattened leaf rank: rows compare lexicographically
+    ([[0], [[0, 4], [1, 0], [1, 2]]], [None, [0, 3]], False),
+    ([[0], [[0, 4], [1, 2], [1, 0]]], [None, [0, 3]], True),
+])
+def test_sorted_coords_guard_on_to_ftensor(monkeypatch, coords, segments,
+                                           bad):
+    monkeypatch.setenv("REPRO_GUARDS", "strict")
+    cs = CSF("X", ["M", "K"], [np.asarray(c) for c in coords], segments,
+             np.arange(1.0, 1.0 + len(coords[1])))
+    if bad:
+        with pytest.raises(GuardViolation, match="sorted-coords"):
+            cs.to_ftensor()
+    else:
+        assert_same_ftensor(cs.to_ftensor(), ref_to_ftensor(cs))
 
 
 # ---------------------------------------------------------------------- #
