@@ -19,10 +19,24 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+from repro.obs.metrics import metrics
+from repro.obs.spans import active_tracer, maybe_span
+
 from .einsum import Einsum, TensorAccess
 from .fibertree import FTensor
 from .spec import (AcceleratorSpec, Directive, EinsumMapping, Flatten,
                    MappingSpec, UniformOccupancy, UniformShape)
+
+
+def _count_leaves(counter: str, ft: FTensor) -> None:
+    """Add the leaves of ``ft`` (those a transform step moved) to
+    ``counter`` on a traced run; untraced, nothing is counted."""
+    if active_tracer() is None:
+        return
+    level = [ft.root]
+    for _ in ft.ranks[1:]:
+        level = [p for f in level for p in f.payloads]
+    metrics().counter(counter).inc(sum(len(f) for f in level))
 
 
 @dataclass
@@ -292,10 +306,14 @@ class MappingResolver:
                 others = [r for r in cur.ranks if r not in key]
                 idx = min(cur.ranks.index(r) for r in key)
                 new_order = others[:idx] + list(key) + others[idx:]
-                cur = cur.swizzle(new_order)
+                with maybe_span("gen:swizzle", "gen"):
+                    cur = cur.swizzle(new_order)
+                _count_leaves("gen.swizzle_leaves", cur)
                 name_acc = key[0]
                 for r in key[1:]:
-                    cur = cur.flatten_ranks(name_acc, r)
+                    with maybe_span("gen:partition", "gen"):
+                        cur = cur.flatten_ranks(name_acc, r)
+                    _count_leaves("gen.partition_leaves", cur)
                     name_acc = name_acc + r
             else:
                 if key not in cur.ranks:
@@ -308,7 +326,9 @@ class MappingResolver:
                 seg = key
                 produced: List[str] = []  # upper ranks created so far
                 for d in dirs:
-                    cur = self._apply_directive(cur, seg, d, out_name)
+                    with maybe_span("gen:partition", "gen"):
+                        cur = self._apply_directive(cur, seg, d, out_name)
+                    _count_leaves("gen.partition_leaves", cur)
                     upper, lower = seg + "1", seg + "0"
                     produced.append(upper)
                     seg = lower
@@ -319,7 +339,9 @@ class MappingResolver:
 
         exec_order = plan.tensors[t].exec_order
         if cur.ranks != exec_order:
-            cur = cur.swizzle(exec_order)
+            with maybe_span("gen:swizzle", "gen"):
+                cur = cur.swizzle(exec_order)
+            _count_leaves("gen.swizzle_leaves", cur)
         return cur
 
     def _apply_directive(self, ft: FTensor, rank: str, d: Directive,

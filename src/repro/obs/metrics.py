@@ -7,6 +7,12 @@ prefix, ``/``-separated label suffix --
                                                         seam calls consumed
                                                         and returned;
                                                         traced runs only)
+    gen.partition_leaves                     counter   (leaves each
+    gen.swizzle_leaves                                  flatten/partition
+                                                        or swizzle of a
+                                                        generator
+                                                        transform moved;
+                                                        traced runs only)
     kernel.downgrade/<action>                counter   (retry/downgrade/
                                                         demote/unavailable)
     kernel.device_call/<seam>                counter   (programs launched

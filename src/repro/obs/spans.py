@@ -5,6 +5,8 @@ The execution layer is instrumented at these nesting levels::
     cascade:<spec>                  CascadeSimulator.run (one simulation)
       gen:transform                 partition / flatten / swizzle of the
                                     inputs, merge detection
+        gen:partition               one flatten or partition directive
+        gen:swizzle                 one rank swizzle
       gen:restore                   exec-form output -> declared form
       model:intake                  the performance model taking events
       model:evaluate                the performance model's report
