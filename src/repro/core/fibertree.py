@@ -16,6 +16,8 @@ fibertrees can be cross-checked against a dense einsum oracle.
 from __future__ import annotations
 
 import bisect
+import gc
+from itertools import chain
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -33,6 +35,14 @@ class Fiber:
         self.coords: List[Coord] = list(coords) if coords else []
         self.payloads: List[Any] = list(payloads) if payloads else []
         assert len(self.coords) == len(self.payloads)
+
+    @classmethod
+    def _adopt(cls, coords: List[Coord], payloads: List[Any]) -> "Fiber":
+        """A Fiber that owns the two given lists (no copy, no check)."""
+        fiber = cls.__new__(cls)
+        fiber.coords = coords
+        fiber.payloads = payloads
+        return fiber
 
     # ------------------------------------------------------------------ #
     # basics
@@ -260,22 +270,34 @@ class FTensor:
     # content-preserving transformations
     # ------------------------------------------------------------------ #
     def swizzle(self, new_order: Sequence[str]) -> "FTensor":
-        """Rank swizzle: reorder fibertree levels to ``new_order``."""
+        """Rank swizzle: reorder fibertree levels to ``new_order``.
+
+        Columnar, in three steps.  A level walk gathers one int64
+        coordinate column per rank, each coordinate repeated down to
+        its leaves (a flattened rank gives one column per tuple
+        position).  One ``np.lexsort`` orders the leaves by the columns
+        taken in ``new_order``.  A bottom-up build then makes each new
+        fiber from slices of the level below, at the prefix changes of
+        the sorted columns.  Leaf payloads move as the same objects;
+        subtrees without leaves are dropped.
+        """
         new_order = list(new_order)
         assert sorted(new_order) == sorted(self.ranks), \
             f"swizzle {self.ranks} -> {new_order} is not a permutation"
         if new_order == self.ranks:
             return self.copy()
-        perm = [self.ranks.index(r) for r in new_order]
         out = FTensor(self.name, new_order, Fiber(),
                       {r: self.rank_shapes.get(r) for r in new_order},
                       self.default, set(self.upper_ranks))
-        for path, val in self.iter_leaves():
-            new_path = [path[i] for i in perm]
-            node = out.root
-            for c in new_path[:-1]:
-                node = node.get_or_create(c, Fiber)
-            node.insert(new_path[-1], val)
+        cols, payloads = _leaf_columns(self.root, len(self.ranks))
+        if payloads:
+            cols = [cols[self.ranks.index(r)] for r in new_order]
+            order = np.lexsort([c[:, j] for c in reversed(cols)
+                                for j in range(c.shape[1] - 1, -1, -1)])
+            payloads = list(map(payloads.__getitem__, order.tolist()))
+            coords, starts = _sorted_levels([c[order] for c in cols])
+            del cols, order           # the build needs the lists alone
+            out.root = _build_bottom_up(coords, starts, payloads)
         return out
 
     def flatten_ranks(self, upper: str, lower: str) -> "FTensor":
@@ -417,6 +439,92 @@ class FTensor:
         shapes = {mapping.get(r, r): s for r, s in self.rank_shapes.items()}
         return FTensor(self.name, ranks, self.root, shapes, self.default,
                        {mapping.get(r, r) for r in self.upper_ranks})
+
+
+# ---------------------------------------------------------------------- #
+# columnar swizzle helpers
+# ---------------------------------------------------------------------- #
+def _leaf_columns(root: Fiber, depth: int
+                  ) -> Tuple[List[np.ndarray], List[Any]]:
+    """Per-rank int64 coordinate columns ``[n_leaves, width]`` and the
+    leaf payloads, in depth-first order, gathered a level at a time:
+    each level's coordinates repeat once per leaf beneath them."""
+    levels: List[np.ndarray] = []
+    fan_out: List[np.ndarray] = []    # children of each element, per level
+    fibers: List[Fiber] = [root]
+    payloads: List[Any] = []
+    for d in range(depth):
+        coords = list(chain.from_iterable(f.coords for f in fibers))
+        payloads = list(chain.from_iterable(f.payloads for f in fibers))
+        if d:
+            fan_out.append(np.fromiter(map(len, fibers), np.int64,
+                                       len(fibers)))
+        if not payloads:
+            return [], []
+        # a flattened rank holds tuple coordinates, all of one length
+        width = len(coords[0]) if isinstance(coords[0], tuple) else 1
+        levels.append(np.array(coords, dtype=np.int64).reshape(-1, width))
+        fibers = payloads
+    # leaves beneath each element, from the leaf level up
+    below = np.ones(len(payloads), dtype=np.int64)
+    cols = [levels[-1]]
+    for d in range(depth - 2, -1, -1):
+        ends = np.cumsum(fan_out[d])
+        csum = np.concatenate(([0], np.cumsum(below)))
+        below = csum[ends] - csum[ends - fan_out[d]]
+        cols.append(np.repeat(levels[d], below, axis=0))
+    cols.reverse()
+    return cols, payloads
+
+
+def _sorted_levels(cols: List[np.ndarray]
+                   ) -> Tuple[List[List[Coord]], List[np.ndarray]]:
+    """For leaf rows sorted outer->inner and distinct, each level's
+    Python coordinates and, above the leaf level, the first row of
+    each of its elements: an element starts wherever the prefix of
+    columns down to its level changes."""
+    n = len(cols[0])
+    coords: List[List[Coord]] = []
+    starts: List[np.ndarray] = []
+    changed = np.zeros(n, dtype=bool)
+    changed[0] = True
+    for c in cols[:-1]:
+        changed[1:] |= np.any(c[1:] != c[:-1], axis=1)
+        starts.append(np.flatnonzero(changed))
+        coords.append(_coord_list(c[starts[-1]]))
+    coords.append(_coord_list(cols[-1]))
+    return coords, starts
+
+
+def _build_bottom_up(coords: List[List[Coord]], starts: List[np.ndarray],
+                     payloads: List[Any]) -> Fiber:
+    """The root of the tree ``_sorted_levels`` describes, with the
+    leaves carrying ``payloads``: every fiber is made from slices of
+    the level below it."""
+    lower = None                      # starts of the level below (leaf: all)
+    enabled = gc.isenabled()
+    gc.disable()                      # Fibers form no cycles
+    try:
+        for d in range(len(coords) - 1, 0, -1):
+            s = starts[d - 1]
+            b = (s if lower is None else np.searchsorted(lower, s)).tolist()
+            b.append(len(payloads))
+            cl, adopt = coords[d], Fiber._adopt
+            payloads = [adopt(cl[lo:hi], payloads[lo:hi])
+                        for lo, hi in zip(b, b[1:])]
+            lower = s
+        return Fiber._adopt(coords[0], payloads)
+    finally:
+        if enabled:
+            gc.enable()
+
+
+def _coord_list(col: np.ndarray) -> List[Coord]:
+    """Python coordinates of a column: ints, or tuples of ints for a
+    flattened rank."""
+    if col.shape[1] == 1:
+        return col[:, 0].tolist()
+    return list(map(tuple, col.tolist()))
 
 
 # ---------------------------------------------------------------------- #

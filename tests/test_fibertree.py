@@ -1,4 +1,7 @@
 """Unit + property tests for the fibertree engine (paper Sec. 2.1/3.2)."""
+import gc
+import itertools
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis, or seeded fallback
@@ -150,3 +153,134 @@ def test_property_swizzle_roundtrip(seed, m, k, n):
     ft = FTensor.from_dense("T", ["M", "K", "N"], a)
     rt = ft.swizzle(["N", "M", "K"]).swizzle(["M", "K", "N"])
     assert np.array_equal(rt.to_dense(), a)
+
+
+# ---------------------------------------------------------------------- #
+# columnar swizzle against the per-leaf algorithm it replaced
+# ---------------------------------------------------------------------- #
+def _swizzle_per_leaf(ft, new_order):
+    """Oracle: insert every leaf from the root down, in the new order."""
+    new_order = list(new_order)
+    if new_order == ft.ranks:
+        return ft.copy()
+    perm = [ft.ranks.index(r) for r in new_order]
+    out = FTensor(ft.name, new_order, Fiber(),
+                  {r: ft.rank_shapes.get(r) for r in new_order},
+                  ft.default, set(ft.upper_ranks))
+    for path, val in ft.iter_leaves():
+        new_path = [path[i] for i in perm]
+        node = out.root
+        for c in new_path[:-1]:
+            node = node.get_or_create(c, Fiber)
+        node.insert(new_path[-1], val)
+    return out
+
+
+def _coord_types(ft):
+    return {type(c) if not isinstance(c, tuple) else
+            (tuple,) + tuple(type(x) for x in c)
+            for path, _ in ft.iter_leaves() for c in path}
+
+
+def _assert_swizzle_matches(ft, new_order):
+    got, want = ft.swizzle(new_order), _swizzle_per_leaf(ft, new_order)
+    assert got.root == want.root
+    assert (got.name, got.ranks, got.rank_shapes, got.upper_ranks,
+            got.default) == (want.name, want.ranks, want.rank_shapes,
+                             want.upper_ranks, want.default)
+    # payloads move as the very objects; coordinates are Python ints
+    got_leaves, want_leaves = list(got.iter_leaves()), list(want.iter_leaves())
+    assert [p for p, _ in got_leaves] == [p for p, _ in want_leaves]
+    assert all(g is w for (_, g), (_, w) in zip(got_leaves, want_leaves))
+    assert _coord_types(got) == _coord_types(want)
+    return got
+
+
+@pytest.mark.parametrize("order", list(itertools.permutations("MKNJ")))
+def test_swizzle_matches_per_leaf(order):
+    a = rand_dense(7, (3, 5, 4, 2), density=0.35)
+    ft = FTensor.from_dense("T", list("MKNJ"), a)
+    got = _assert_swizzle_matches(ft, order)
+    assert got.nnz == int(np.count_nonzero(a))
+    if list(order) == ft.ranks:
+        assert got is not ft and got.root is not ft.root
+
+
+def _partitioned():
+    a = rand_dense(8, (4, 9, 3), density=0.4)
+    ft = FTensor.from_dense("T", ["M", "K", "N"], a)
+    return ft.partition_uniform_occupancy("K", 2), ["K1", "N", "M", "K0"]
+
+
+def _partitioned_shape():
+    a = rand_dense(9, (4, 9, 3), density=0.4)
+    ft = FTensor.from_dense("T", ["M", "K", "N"], a, default=float("inf"))
+    return ft.partition_uniform_shape("M", 3), ["K", "M0", "N", "M1"]
+
+
+def _flattened():
+    a = rand_dense(10, (4, 5, 3), density=0.4)
+    ft = FTensor.from_dense("T", ["M", "K", "N"], a)
+    return ft.flatten_ranks("M", "K"), ["N", "MK"]
+
+
+def _flattened_inner():
+    a = rand_dense(11, (3, 4, 5), density=0.4)
+    ft = FTensor.from_dense("T", ["M", "K", "N"], a)
+    fl = ft.flatten_ranks("K", "N").partition_uniform_occupancy("KN", 3)
+    return fl, ["KN0", "M", "KN1"]
+
+
+def _empty_inner():
+    root = Fiber([0, 1, 2, 4], [
+        Fiber([0, 3], [Fiber([], []), Fiber([2], [1.5])]),
+        Fiber([], []),
+        Fiber([3], [Fiber([1, 2], [5.0, 6.0])]),
+        Fiber([1], [Fiber([], [])]),
+    ])
+    ft = FTensor("T", ["A", "B", "C"], root, {"A": 5, "B": 4, "C": 3})
+    return ft, ["C", "A", "B"]
+
+
+def _payload_types():
+    inf = float("inf")
+    root = Fiber([0, 2], [
+        Fiber([1, 3], [Fiber([0, 4], [7, True]), Fiber([2], [inf])]),
+        Fiber([0, 3], [Fiber([4], [False]), Fiber([0, 1], [2.5, -inf])]),
+    ])
+    ft = FTensor("D", ["V", "S", "W"], root, {"V": 3, "S": 4, "W": 5},
+                 default=inf)
+    return ft, ["W", "V", "S"]
+
+
+def _all_leaves_empty():
+    root = Fiber([1, 2], [Fiber([0], [Fiber([], [])]),
+                          Fiber([], [])])
+    return FTensor("T", ["A", "B", "C"], root, {"A": 3}), ["B", "C", "A"]
+
+
+def _empty():
+    return FTensor("E", ["M", "K"], Fiber(), {"M": 3, "K": 4}), ["K", "M"]
+
+
+@pytest.mark.parametrize("case", [
+    _partitioned, _partitioned_shape, _flattened, _flattened_inner,
+    _empty_inner, _payload_types, _all_leaves_empty, _empty,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_swizzle_matches_per_leaf_on(case):
+    ft, order = case()
+    got = _assert_swizzle_matches(ft, order)
+    assert got.content_signature() == _swizzle_per_leaf(
+        ft, order).content_signature()
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_swizzle_leaves_the_collector_as_it_was(enabled):
+    ft = FTensor.from_dense("T", ["M", "K"], rand_dense(12, (5, 6)))
+    was = gc.isenabled()
+    try:
+        (gc.enable if enabled else gc.disable)()
+        ft.swizzle(["K", "M"])
+        assert gc.isenabled() is enabled
+    finally:
+        (gc.enable if was else gc.disable)()
